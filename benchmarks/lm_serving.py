@@ -1,19 +1,22 @@
-"""CI smoke for the LM serving path (ISSUE 10).
+"""CI smoke for the LM serving path.
 
 Builds a seq-bucketed ``LMSession`` (buckets solved from a synthetic
 prompt-length histogram by the traffic DP), saves the v5 artifact, then
-**reloads it in a separate process** (fresh interpreter, cold caches)
-and gates there:
+loads it back with ``LMSession.load`` and gates on the loaded session:
 
 * load -> generate runs **zero** schedule searches
-  (``core.local_search.search_calls()`` spy), and every generation is
-  bit-identical to the tokens the parent produced before saving;
+  (``core.local_search.search_calls()`` does not move across it), and
+  every generation is bit-identical to the tokens the built session
+  produced before saving;
 * ``AsyncServer.submit_stream`` tokens are bit-identical to the
   non-streamed ``generate`` loop (stream == batch semantics), and
   streams execute alone (batch_hist.max_size == 1).
 
+Build and reload share one process: a JAX device belongs to one process
+at a time, so a child could not get the chip the parent holds.
+
 Writes BENCH_lm.json with the solved bucket set, load/prewarm wall
-times, and decode throughput from the child.
+times, and decode throughput of the loaded session.
 
     PYTHONPATH=../src python lm_serving.py --smoke --out ../BENCH_lm.json
 """
@@ -21,77 +24,64 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
-import subprocess
-import sys
 import tempfile
 import time
 from pathlib import Path
 
 import numpy as np
 
-_CHILD = r"""
-import json
-import sys
-import time
 
-import numpy as np
-import jax.numpy as jnp
+def check_loaded(artifact: Path, prompts, want, gen: int) -> dict:
+    """Load the artifact and run both gates on it; returns the timings."""
+    import jax.numpy as jnp
 
-artifact, out_json, gen = sys.argv[1], sys.argv[2], int(sys.argv[3])
-from repro.core.local_search import search_calls
-from repro.engine import AsyncServer, DynamicBatchPolicy, LMSession
+    from repro.core.local_search import search_calls
+    from repro.engine import AsyncServer, DynamicBatchPolicy, LMSession
 
-t0 = time.perf_counter()
-sess = LMSession.load(artifact)
-t_load = time.perf_counter() - t0
-t0 = time.perf_counter()
-sess.prewarm()
-t_warm = time.perf_counter() - t0
+    n_searches = search_calls()
+    t0 = time.perf_counter()
+    sess = LMSession.load(artifact)
+    t_load = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    sess.prewarm()
+    t_warm = time.perf_counter() - t0
 
-prompts = np.load(artifact + "/smoke_prompts.npz")
-want = np.load(artifact + "/smoke_tokens.npz")
-keys = sorted(prompts.files, key=int)
+    # gate 1: load -> generate is zero-search and bit-identical
+    t0 = time.perf_counter()
+    plain = {k: np.asarray(sess.generate(jnp.asarray(p), gen))
+             for k, p in prompts.items()}
+    t_gen = time.perf_counter() - t0
+    assert search_calls() == n_searches, \
+        f"load->generate ran {search_calls() - n_searches} schedule " \
+        "searches (want 0)"
+    for k in prompts:
+        assert plain[k].tobytes() == want[k].tobytes(), \
+            f"reload token drift on prompt {k}"
 
-# gate 1: load -> generate is zero-search and bit-identical cross-process
-t0 = time.perf_counter()
-plain = {}
-for k in keys:
-    plain[k] = np.asarray(sess.generate(jnp.asarray(prompts[k]), gen))
-t_gen = time.perf_counter() - t0
-assert search_calls() == 0, \
-    f"load->generate ran {search_calls()} schedule searches (want 0)"
-for k in keys:
-    assert plain[k].tobytes() == want[k].tobytes(), \
-        f"cross-process token drift on prompt {k}"
-
-# gate 2: streamed decode == the non-streamed loop, bit for bit, and
-# each stream executed alone
-srv = AsyncServer(sess, DynamicBatchPolicy(max_batch=4, max_wait_ms=1.0))
-try:
-    streams = [(k, srv.submit_stream(jnp.asarray(prompts[k]), gen))
-               for k in keys]
-    for k, s in streams:
-        toks = [np.asarray(t) for t in s]
-        assert len(toks) == gen, f"stream {k} yielded {len(toks)} steps"
-        got = np.stack(toks, axis=1)
-        assert got.tobytes() == plain[k].tobytes(), \
-            f"streamed tokens drifted from generate on prompt {k}"
-finally:
-    srv.close(drain=True)
-assert search_calls() == 0, "streaming ran a schedule search"
-assert srv.stats.batch_hist.max_size == 1, \
-    "a stream was packed with other requests"
-
-n_tok = gen * len(keys) * sess.batch
-json.dump({"t_load_s": round(t_load, 4), "t_prewarm_s": round(t_warm, 4),
-           "decode_tok_per_s": round(n_tok / t_gen, 2),
-           "n_generations": len(keys), "zero_search": True,
-           "stream_bit_identical": True},
-          open(out_json, "w"), indent=2)
-print(f"child process: {len(keys)} generations zero-search, streamed == "
-      f"generate bit-identical (seq_buckets={sess.seq_buckets})")
-"""
+    # gate 2: streamed decode == the non-streamed loop, bit for bit, and
+    # each stream executed alone
+    srv = AsyncServer(sess, DynamicBatchPolicy(max_batch=4, max_wait_ms=1.0))
+    try:
+        streams = [(k, srv.submit_stream(jnp.asarray(p), gen))
+                   for k, p in prompts.items()]
+        for k, s in streams:
+            toks = [np.asarray(t) for t in s]
+            assert len(toks) == gen, f"stream {k} yielded {len(toks)} steps"
+            assert np.stack(toks, axis=1).tobytes() == plain[k].tobytes(), \
+                f"streamed tokens drifted from generate on prompt {k}"
+    finally:
+        srv.close(drain=True)
+    assert search_calls() == n_searches, "streaming ran a schedule search"
+    assert srv.stats.batch_hist.max_size == 1, \
+        "a stream was packed with other requests"
+    print(f"loaded session: {len(prompts)} generations zero-search, "
+          f"streamed == generate bit-identical "
+          f"(seq_buckets={sess.seq_buckets})")
+    n_tok = gen * len(prompts) * sess.batch
+    return {"t_load_s": round(t_load, 4), "t_prewarm_s": round(t_warm, 4),
+            "decode_tok_per_s": round(n_tok / t_gen, 2),
+            "n_generations": len(prompts), "zero_search": True,
+            "stream_bit_identical": True}
 
 
 def main() -> None:
@@ -142,30 +132,24 @@ def main() -> None:
     out_dir = Path(args.artifact_out) if args.artifact_out else \
         Path(tempfile.mkdtemp(prefix="lm_smoke_")) / "ARTIFACT_lm"
     sess.save(out_dir)
-    np.savez(out_dir / "smoke_prompts.npz", **prompts)
-    np.savez(out_dir / "smoke_tokens.npz", **tokens)
     print(f"saved LM artifact to {out_dir} (arch={args.arch}, "
           f"max_len={args.max_len}, seq_buckets={sess.seq_buckets}, "
           f"expected catch-up {catchup} decode tokens on the histogram)")
-
-    env = dict(os.environ)
-    src = str(Path(__file__).resolve().parent.parent / "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    child_json = out_dir / "child_report.json"
-    subprocess.run([sys.executable, "-c", _CHILD, str(out_dir),
-                    str(child_json), str(args.gen)], check=True, env=env)
-    child = json.loads(child_json.read_text())
+    loaded = check_loaded(out_dir, prompts, tokens, args.gen)
 
     report = {"benchmark": "lm_serving", "arch": args.arch,
               "family": cfg.family, "max_len": args.max_len,
               "gen": args.gen, "seq_buckets": list(sess.seq_buckets),
               "prompt_hist": {str(k): v for k, v in sorted(hist.items())},
               "expected_catchup_tokens": catchup,
-              "t_compile_s": round(t_compile, 4), **child}
+              "t_compile_s": round(t_compile, 4), **loaded}
     Path(args.out).write_text(json.dumps(report, indent=2) + "\n")
-    print(f"wrote {args.out}: LM artifact cross-process round-trip OK "
+    print(f"wrote {args.out}: LM artifact round-trip OK "
           f"(zero search, streamed == generate)")
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

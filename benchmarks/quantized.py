@@ -196,4 +196,7 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     raise SystemExit(main())

@@ -12,11 +12,11 @@ import argparse
 import json
 from pathlib import Path
 
+from repro.analysis.roofline import ICI_BW
+
 ROOT = Path(__file__).resolve().parents[1] / "experiments"
 DRYRUN_DIR = ROOT / "dryrun"
 PERF_DIR = ROOT / "perf"
-
-ICI_BW = 50e9
 
 
 def load(mesh: str):

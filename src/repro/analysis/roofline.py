@@ -1,8 +1,9 @@
-"""Roofline analysis from compiled dry-run artifacts (TPU v5e terms).
+"""Roofline analysis from compiled dry-run artifacts, in the terms of the
+planner's target chip (``core.peaks``):
 
-    compute term    = HLO_FLOPs / (chips x 197 TFLOP/s bf16)
-    memory term     = HLO_bytes / (chips x 819 GB/s)
-    collective term = collective_bytes / (chips x ~50 GB/s/link)
+    compute term    = HLO_FLOPs / (chips x peak bf16 FLOP/s)
+    memory term     = HLO_bytes / (chips x HBM bytes/s)
+    collective term = collective_bytes / (chips x ICI bytes/s per link)
 
 ``compiled.cost_analysis()`` supplies FLOPs/bytes of the *per-device*
 partitioned module; collective bytes are parsed from the optimized HLO text
@@ -15,10 +16,12 @@ import dataclasses
 import re
 from typing import Dict, Optional, Tuple
 
-# v5e datasheet (same constants as core.cost)
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # bytes/s / chip
-ICI_BW = 50e9                # bytes/s / link (per direction)
+from repro.core.peaks import PLAN_TARGET, peaks
+
+_TARGET = peaks(PLAN_TARGET)
+PEAK_FLOPS = _TARGET.bf16_flops               # FLOP/s / chip
+HBM_BW = _TARGET.hbm_bytes_per_s              # bytes/s / chip
+ICI_BW = _TARGET.ici_bytes_per_s_per_link     # bytes/s / link
 
 _DTYPE_BYTES = {
     "f64": 8, "s64": 8, "u64": 8, "c64": 8,

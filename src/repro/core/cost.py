@@ -1,18 +1,13 @@
-"""Roofline cost model (TPU v5e) for schedules, transforms, and collectives.
+"""Roofline cost model for schedules, transforms, and collectives.
 
-NeoCPU's local search *measures* wall time on the target CPU.  This container
-has no TPU, so the measured signal is replaced (optionally augmented — see
-``local_search.measured_runner``) by an analytical roofline model built from
-the v5e datasheet numbers the roofline analysis also uses:
-
-    peak bf16 compute : 197 TFLOP/s / chip   (fp32 via MXU ≈ half)
-    HBM bandwidth     : 819 GB/s / chip
-    ICI link bandwidth: ~50 GB/s / link (per direction)
-    VMEM              : ~16 MiB / core
+NeoCPU's local search *measures* wall time on the target.  The default
+scoring signal here is an analytical roofline model of the planner's
+target chip (``core.peaks.PLAN_TARGET``), built from that chip's row of
+the peak table (``core.peaks``); ``local_search.measured_runner`` can
+replace it with wall-clock measurement.
 
 The model is intentionally coarse — it only has to *rank* schedules the way a
-real measurement would, and its three terms are exactly the roofline terms
-reported in EXPERIMENTS.md §Roofline.
+real measurement would.  It is a prediction, not a measurement.
 """
 from __future__ import annotations
 
@@ -21,13 +16,16 @@ import math
 from typing import Tuple
 
 from repro.core.layout import Layout, transform_bytes
+from repro.core.peaks import PLAN_TARGET, VMEM_BUDGET, peaks
 from repro.core.schedule import ConvSchedule, ConvWorkload
 
-PEAK_FLOPS_BF16 = 197e12
-PEAK_FLOPS_FP32 = 98.5e12
-HBM_BW = 819e9
-ICI_BW_PER_LINK = 50e9
-VMEM_BYTES = 16 * 1024 * 1024
+_TARGET = peaks(PLAN_TARGET)
+PEAK_FLOPS_BF16 = _TARGET.bf16_flops
+# model assumption, not a published peak: fp32 operands run the MXU at
+# half its bf16 rate
+PEAK_FLOPS_FP32 = PEAK_FLOPS_BF16 / 2
+HBM_BW = _TARGET.hbm_bytes_per_s
+ICI_BW_PER_LINK = _TARGET.ici_bytes_per_s_per_link
 MXU_DIM = 128
 SUBLANE = 8
 
@@ -68,18 +66,64 @@ def mxu_utilization(m: int, k: int, n: int) -> float:
     return um * uk * un
 
 
+def _tile_bytes(*dims: int, dtype_bytes: int = 4) -> int:
+    """VMEM bytes of one block: the minor dim pads to the 128 lanes, the
+    second-minor to the 8 sublanes."""
+    *lead, sub, lane = dims
+    n = _round_up(sub, SUBLANE) * _round_up(lane, MXU_DIM) * dtype_bytes
+    for d in lead:
+        n *= d
+    return n
+
+
+def pool_lanes(oc_bn: int) -> int:
+    """Lane width of one chunk of the Pallas kernel's pooling scratch: a
+    strided read on the TPU takes a minor dim of at most 128 lanes, so a
+    wider ``oc_bn`` plane is kept as ``oc_bn // lanes`` chunks."""
+    return max(d for d in range(1, min(oc_bn, MXU_DIM) + 1) if oc_bn % d == 0)
+
+
 def conv_vmem_bytes(wl: ConvWorkload, s: ConvSchedule) -> int:
-    """Working set per grid step of the Pallas kernel (see conv2d_nchwc.py):
-    one (H_pad, W_pad, ic_bn) input slab, the (kh, kw, ic_bn, oc_bn) weight
-    block, and the (oh_bn, OW, oc_bn) output block (fp32 accumulator)."""
+    """Tiled VMEM footprint of one grid step of the Pallas kernel
+    (``kernels/conv2d_nchwc.py``): the (H_pad, stride, W_pad/stride, ic_bn)
+    phase-split input slab (single-buffered when it is the only channel
+    chunk, as the kernel stages it), the (kh, kw, ic_bn, oc_bn) weight block, the fp32
+    (oh_bn, OW, oc_bn) output block, the residual / concat-buffer blocks,
+    and the fused-pooling scratch plane.  Pipelined blocks count twice
+    (double buffering); every block pads to the (8, 128) tile."""
     oh, ow = wl.out_hw
     h_pad = wl.height + 2 * wl.pad
     w_pad = wl.width + 2 * wl.pw
+    cin = wl.in_channels // wl.groups
     b = wl.dtype_bytes
-    inp = h_pad * w_pad * s.ic_bn * b
-    ker = wl.kh * wl.kw * s.ic_bn * s.oc_bn * (1 if s.dtype == "int8" else b)
-    outp = s.oh_bn * ow * s.oc_bn * 4  # fp32 accum
-    return inp + ker + outp
+    inp = _tile_bytes(h_pad, wl.stride, -(-w_pad // wl.stride), s.ic_bn,
+                      dtype_bytes=b) \
+        * (1 if cin == s.ic_bn else 2)
+    ker = 2 * _tile_bytes(wl.kh, wl.kw, s.ic_bn, s.oc_bn, dtype_bytes=b)
+    vecs = 2 * 2 * _tile_bytes(1, s.oc_bn)          # scale + shift rows
+    spec = wl.epilogue_spec()
+    if spec.pool is not None:
+        out_h, out_w = spec.pool.out_hw(oh, ow)
+        outp = 2 * _tile_bytes(out_h, out_w, s.oc_bn)
+        lanes = pool_lanes(s.oc_bn)
+        scratch = (s.oc_bn // lanes) * _tile_bytes(
+            *spec.pool.padded_hw(oh, ow), lanes)
+        rows = oh
+    else:
+        outp = 2 * _tile_bytes(s.oh_bn, ow, s.oc_bn)
+        scratch = 0
+        rows = s.oh_bn
+    res = 2 * _tile_bytes(rows, ow, s.oc_bn, dtype_bytes=b) \
+        if wl.fused_residual else 0
+    buf = outp if wl.concat_total else 0
+    return inp + ker + vecs + outp + scratch + res + buf
+
+
+def fits_vmem(wl: ConvWorkload, s: ConvSchedule) -> bool:
+    """Whether the Pallas kernel can stage schedule ``s`` within the VMEM
+    budget it asks the compiler for; the local search never ranks a
+    schedule that does not."""
+    return conv_vmem_bytes(wl, s) <= VMEM_BUDGET
 
 
 def conv_schedule_cost(wl: ConvWorkload, s: ConvSchedule,
@@ -169,10 +213,6 @@ def conv_schedule_cost(wl: ConvWorkload, s: ConvSchedule,
         dtype_bytes=b)
     memory_s = (input_bytes + weight_bytes + output_bytes + variant_bytes
                 + epi_bytes) / HBM_BW
-
-    # schedules that spill VMEM pay a heavy penalty (they would thrash HBM)
-    if conv_vmem_bytes(wl, s) > VMEM_BYTES:
-        memory_s *= 8.0
     return CostBreakdown(compute_s=compute_s, memory_s=memory_s)
 
 

@@ -116,6 +116,13 @@ class PoolSpec:
         return _pool_out_hw(h, w, self.k, self.stride, self.pad,
                             self.ceil_mode)
 
+    def padded_hw(self, h: int, w: int) -> Tuple[int, int]:
+        """Extent of the ``(h, w)`` plane once ``pool2d``'s padding is laid
+        around it (top/left ``pad``, bottom/right to the last window)."""
+        oh, ow = self.out_hw(h, w)
+        return (max(self.pad + h, (oh - 1) * self.stride + self.k),
+                max(self.pad + w, (ow - 1) * self.stride + self.k))
+
     def apply(self, x: jnp.ndarray) -> jnp.ndarray:
         """Run this pooling reduction over axes (2, 3) of ``x``."""
         return pool2d(x, self.k, self.stride, self.pad, self.ceil_mode,

@@ -7,11 +7,11 @@ appearing in different models is never searched twice.
 
 We keep that machinery intact.  The *scoring signal* is pluggable:
 
-* ``roofline_runner`` (default) — the v5e analytical cost model from
-  ``core.cost``; deterministic and fast, ranks schedules the way a
-  measurement on the target would.
-* ``measured_runner`` — wall-clock of the jnp template instantiation on the
-  host CPU (the paper's own methodology, usable in this container).
+* ``roofline_runner`` (default) — the analytical cost model of the
+  planner's target chip (``core.cost``); deterministic and fast, ranks
+  schedules the way a measurement on the target would.
+* ``measured_runner`` — wall-clock of the jnp template instantiation on
+  whatever backend runs the search (the paper's own methodology).
 """
 from __future__ import annotations
 
@@ -21,7 +21,7 @@ import time
 from pathlib import Path
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
-from repro.core.cost import CostBreakdown, conv_schedule_cost
+from repro.core.cost import CostBreakdown, conv_schedule_cost, fits_vmem
 from repro.core.schedule import ConvSchedule, ConvWorkload, candidate_schedules
 
 Runner = Callable[[ConvWorkload, ConvSchedule], float]
@@ -162,7 +162,12 @@ class LocalSearchResult:
 def local_search(wl: ConvWorkload, runner: Runner = roofline_runner,
                  max_candidates: int = 0) -> LocalSearchResult:
     SEARCH_COUNTERS["local_search"] += 1
+    # a schedule past the Pallas kernel's VMEM budget is ranked only when
+    # no schedule is within it (a plane too large for the kernel's
+    # layout): the jnp templates still run it, and the kernel's own
+    # compile decides whether it fits
     cands = candidate_schedules(wl, max_candidates=max_candidates)
+    cands = [s for s in cands if fits_vmem(wl, s)] or cands
     scored = [RankedSchedule(s, runner(wl, s)) for s in cands]
     scored.sort(key=lambda r: (r.cost_s, r.schedule))
     return LocalSearchResult(workload=wl, ranked=scored)

@@ -29,20 +29,20 @@ Two dispatch modes:
   a fused plan dispatches one kernel where the unfused plan dispatches
   conv + BN + add + ReLU.
 
-Multi-core execution (two orthogonal levers, both riding on forced host
-devices — ``repro.launch.cpu.configure_cpu_devices``):
+Multi-device execution (two orthogonal levers over ``jax.devices()`` —
+the chips of a TPU host, or forced host CPU devices on a CPU-only machine):
 
 * ``devices=D`` — **intra-op** data parallelism: the whole-graph forward
-  is wrapped in ``shard_map`` over a 1-D ``("data",)`` mesh of D host
-  devices, splitting the batch axis so every device runs the *same*
-  per-core NCHW[x]c program on a B/D sub-batch (the plan is built at the
+  is wrapped in ``jax.shard_map`` over a 1-D ``("data",)`` mesh of the
+  first D devices, splitting the batch axis so every device runs the
+  *same* NCHW[x]c program on a B/D sub-batch (the plan is built at the
   sub-batch shape; sharding composes *above* the templates).  Parameters
   are replicated once at bind.  Batches must divide by D.
 * :meth:`CompiledModel.replica` — **inter-op** replicas: the same
-  executable with its parameters committed to another host device, so
+  executable with its parameters committed to another device, so
   concurrent serving workers execute on distinct devices (one program
-  copy per device, compiled lazily on first use; numerics are identical
-  — same code, same host — so the serving bit-identical guarantee holds
+  copy per device, compiled lazily on first use; the same program runs on
+  every device of one kind, so the serving bit-identical guarantee holds
   per fixed (bucket, device-count) program regardless of which worker
   ran the batch).
 """
@@ -188,7 +188,7 @@ def bind_params(plan: Plan, params: Params, fold_bn: bool = True,
 
 
 def _eval_node(node, lay: Layout, schedule, use_pallas: bool,
-               interpret: bool, p: Dict[str, jnp.ndarray],
+               interpret: Optional[bool], p: Dict[str, jnp.ndarray],
                *ins: jnp.ndarray) -> jnp.ndarray:
     """One graph node on already-computed inputs — shared by both dispatch
     modes (the whole-graph jit and the per-node graph-runtime path)."""
@@ -265,37 +265,36 @@ def _eval_node(node, lay: Layout, schedule, use_pallas: bool,
 
 
 def _device_mesh(devices: int):
-    """1-D ("data",) mesh over the first ``devices`` host devices, with
-    the actionable error when the process was not configured for them."""
+    """1-D ("data",) mesh over the first ``devices`` of ``jax.devices()``;
+    fails when the process sees fewer."""
     from jax.sharding import Mesh
 
     devs = jax.devices()
     if len(devs) < devices:
         raise RuntimeError(
-            f"plan wants {devices} devices but this process has "
-            f"{len(devs)}; call repro.launch.cpu.configure_cpu_devices"
-            f"({devices}) before the first JAX use (or set XLA_FLAGS="
-            f"--xla_force_host_platform_device_count={devices})")
+            f"plan wants {devices} devices but this process sees "
+            f"{len(devs)} ({devs[0].platform}); compile with devices <= "
+            f"{len(devs)}")
     return Mesh(np.asarray(devs[:devices]), ("data",))
 
 
 @dataclasses.dataclass
 class CompiledModel:
     """Callable end-to-end executable for one plan.  ``devices > 1``
-    executes batch-sharded over a host-device mesh (see module docs)."""
+    executes batch-sharded over a device mesh (see module docs)."""
 
     plan: Plan
     params: Params               # pre-transformed (bind_params output)
     use_pallas: bool = False
-    interpret: bool = True
+    interpret: Optional[bool] = None   # None: compiled on TPU only
     dispatch: str = "whole"      # "whole" (one jit) | "op" (per-node jit)
-    devices: int = 1             # batch-sharded over this many host devices
+    devices: int = 1             # batch-sharded over this many devices
 
     def __post_init__(self):
         structure = self.plan.planned
         use_pallas, interpret = self.use_pallas, self.interpret
         topo = structure.graph.topo_order()
-        self._replicas: Dict[int, "_DeviceReplica"] = {}
+        self._replicas: Dict[Any, "_DeviceReplica"] = {}
 
         if self.dispatch not in ("whole", "op"):
             raise ValueError(f"unknown dispatch mode {self.dispatch!r}")
@@ -328,17 +327,16 @@ class CompiledModel:
             return outs[0] if len(outs) == 1 else tuple(outs)
 
         if self.devices > 1:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import NamedSharding, PartitionSpec as P
 
             mesh = _device_mesh(self.devices)
             self._mesh = mesh
             # params replicated (P()), every input/output batch-sharded
-            # (P("data") partitions the leading axis); check_rep off so
+            # (P("data") partitions the leading axis); check_vma off so
             # Pallas calls inside the forward stay legal per-shard
-            sharded = shard_map(forward, mesh=mesh,
-                                in_specs=(P(), P("data")),
-                                out_specs=P("data"), check_rep=False)
+            sharded = jax.shard_map(forward, mesh=mesh,
+                                    in_specs=(P(), P("data")),
+                                    out_specs=P("data"), check_vma=False)
             self._forward = jax.jit(sharded)
             # replicate once at bind, not per call
             self.params = jax.device_put(
@@ -375,39 +373,39 @@ class CompiledModel:
     def replica(self, device=None) -> "CompiledModel | _DeviceReplica":
         """The same program with parameters resident on ``device`` — the
         inter-op serving replica (each ``AsyncServer`` worker executes on
-        its own host device).  Shares this model's jitted forward: JAX
+        its own device).  Shares this model's jitted forward: JAX
         dispatches on the committed parameters' device, compiling one
         executable per device lazily.  Sharded models (``devices > 1``)
         already span the mesh and return ``self``."""
         if device is None or self.devices > 1:
             return self
-        key = getattr(device, "id", device)
-        rep = self._replicas.get(key)
+        # keyed by the device itself: ids repeat across platforms
+        rep = self._replicas.get(device)
         if rep is None:
             rep = _DeviceReplica(self, device)
-            self._replicas[key] = rep
+            self._replicas[device] = rep
         return rep
 
 
 class _DeviceReplica:
-    """One ``CompiledModel`` executing on a specific host device (shared
+    """One ``CompiledModel`` executing on a specific device (shared
     jitted forward, device-committed parameter copy)."""
 
     def __init__(self, model: CompiledModel, device) -> None:
         self.model = model
         self.device = device
         self.plan = model.plan
-        self._params = jax.device_put(model.params, device)
+        self.params = jax.device_put(model.params, device)
 
     def __call__(self, inputs: Dict[str, jnp.ndarray]):
-        return self.model._forward(self._params, inputs)
+        return self.model._forward(self.params, inputs)
 
     def predict(self, x: jnp.ndarray):
         return self(inputs={self.model.input_name: x})
 
 
 def compile_model(plan: Plan, params: Params, use_pallas: bool = False,
-                  interpret: bool = True, fold_bn: bool = True,
+                  interpret: Optional[bool] = None, fold_bn: bool = True,
                   dispatch: str = "whole", devices: int = 1) -> CompiledModel:
     bound = bind_params(plan, params, fold_bn=fold_bn, use_pallas=use_pallas)
     return CompiledModel(plan=plan, params=bound, use_pallas=use_pallas,

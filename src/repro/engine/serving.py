@@ -54,13 +54,13 @@ bounded FIFO queue; batches still *form* strictly FIFO under the server
 lock, but up to N of them *execute* concurrently — inter-op data
 parallelism across requests.  Each worker executes through a per-device
 **program replica** (``CompiledModel.replica``: the same bucket program
-with parameters committed to host device ``i``), so on a process
-configured with multiple host devices
-(``repro.launch.cpu.configure_cpu_devices``) the workers run on distinct
-devices instead of contending for one.  Results stay bit-identical to
-single-worker serving: every replica is the same fixed-shape program on
-the same host, so a request's result depends only on its (bucket,
-device-count) program and its batch — never on which worker ran it.
+with parameters committed to device ``i`` of ``devices``, by default
+``jax.devices()`` — the chips of a TPU host, or forced host devices on a
+CPU run), so the workers run on distinct devices instead of contending
+for one.  Results stay bit-identical to single-worker serving: every
+replica is the same fixed-shape program on devices of one kind, so a
+request's result depends only on its (bucket, device-count) program and
+its batch — never on which worker ran it.
 ``pin="auto"`` additionally pins each worker thread to its own CPU set
 (``repro.launch.cpu.worker_cpu_sets`` / ``maybe_pin``), keeping the
 scheduler from migrating workers mid-batch.
@@ -578,8 +578,9 @@ class AsyncServer:
     ``workers`` worker threads pack (FIFO, under one lock) and execute
     batches; with more than one, each worker executes through its own
     per-device program replica (``CompiledModel.replica``) so batches run
-    concurrently on distinct host devices — see the module docs for why
-    results stay bit-identical to single-worker serving.  ``pin="auto"``
+    concurrently on distinct devices (``devices``, default
+    ``jax.devices()``) — see the module docs for why results stay
+    bit-identical to single-worker serving.  ``pin="auto"``
     gives each worker thread its own CPU affinity set; an explicit
     ``pin`` is a list of one CPU set per worker.
 
@@ -595,6 +596,7 @@ class AsyncServer:
 
     def __init__(self, session, policy: Optional[BatchPolicy] = None, *,
                  max_queue: int = 128, workers: int = 1,
+                 devices: Optional[Sequence] = None,
                  pin=None,
                  retry: Optional[RetryPolicy] = None,
                  shed: str = "newest",
@@ -627,6 +629,7 @@ class AsyncServer:
         self.priority_default = priority_default
         self.max_queue = max_queue
         self.workers = workers
+        self._devices = list(devices) if devices is not None else None
         self._pin_sets = self._resolve_pin(pin, workers)
         self.retry = retry if retry is not None else RetryPolicy()
         self.shed = shed
@@ -1007,11 +1010,11 @@ class AsyncServer:
     def _model_for(self, bucket: int, worker: int):
         """The executable this worker runs ``bucket`` through: the shared
         specialization for worker 0 (and single-worker servers), a
-        same-program replica committed to host device ``worker % D`` for
-        the rest — identical numerics, concurrent execution."""
+        same-program replica committed to device ``worker % D`` for the
+        rest — identical numerics, concurrent execution."""
         m = self.session.specialize(bucket)
         if self.workers > 1 and getattr(m, "devices", 1) == 1:
-            devs = jax.devices()
+            devs = self._devices or jax.devices()
             if len(devs) > 1:
                 return m.replica(devs[worker % len(devs)])
         return m

@@ -294,7 +294,8 @@ class InferenceSession:
                  tuning: str = "roofline",
                  transform_bw: Optional[float] = None,
                  search_budget: Tuple[int, int, int] = (6, 2, 3),
-                 use_pallas: bool = False, interpret: bool = True,
+                 use_pallas: bool = False,
+                 interpret: Optional[bool] = None,
                  dispatch: str = "whole", devices: int = 1,
                  dtype: str = "fp32",
                  model_name: Optional[str] = None) -> None:
@@ -587,7 +588,6 @@ class InferenceSession:
             "pipeline": self.pipeline.name if self.pipeline else None,
             "input_spec": {k: list(v) for k, v in self._base_shapes.items()},
             "use_pallas": self.use_pallas,
-            "interpret": self.interpret,
             "dispatch": self.dispatch,
             "devices": self.devices,
             "specializations": specs,
@@ -630,7 +630,7 @@ class InferenceSession:
         (v2 ``include_source``), the loaded session is *not* frozen and
         may specialize unseen batch sizes on demand.
 
-        ``devices`` re-targets the artifact to a different host-device
+        ``devices`` re-targets the artifact to a different device
         count (the scaling benchmark loads *one* artifact at every device
         count).  Plans are built at the per-device sub-batch, so a
         re-targeted load drops the saved specializations and re-plans from
@@ -740,7 +740,6 @@ class InferenceSession:
                    search_budget=tuple(
                        (source or {}).get("search_budget", (6, 2, 3))),
                    use_pallas=manifest.get("use_pallas", False),
-                   interpret=manifest.get("interpret", True),
                    dispatch=dispatch or manifest.get("dispatch", "whole"),
                    devices=devices if retarget else saved_devices,
                    dtype=(manifest.get("quantized") or {}).get("dtype",
@@ -805,7 +804,7 @@ def compile(model: Union[str, Graph],                     # noqa: A001
             transform_bw: Optional[float] = None,
             search_budget: Tuple[int, int, int] = (6, 2, 3),
             seed: int = 0,
-            use_pallas: bool = False, interpret: bool = True,
+            use_pallas: bool = False, interpret: Optional[bool] = None,
             dispatch: str = "whole", devices: int = 1,
             dtype: str = "fp32",
             eager: bool = True) -> InferenceSession:
@@ -826,10 +825,12 @@ def compile(model: Union[str, Graph],                     # noqa: A001
     pipeline    a ``core.pipeline.Pipeline``; default is the full ladder
                 (``Pipeline.preset("fusion")``)
     db          schedule database instance or path to a persisted one
-    devices     batch-shard every specialization over this many host
-                devices (``shard_map`` over a 1-D data mesh; requires
-                ``repro.launch.cpu.configure_cpu_devices(devices)``
-                before the first JAX use).  Batch sizes must divide by
+    use_pallas  run blocked convs through the Pallas kernel instead of the
+                jnp templates; ``interpret`` (default None) compiles it
+                on TPU and interprets it elsewhere
+    devices     batch-shard every specialization over the first this many
+                of ``jax.devices()`` (``jax.shard_map`` over a 1-D data
+                mesh).  Batch sizes must divide by
                 it — sharding composes *above* the per-core NCHW[x]c
                 templates, so ``candidate_schedules`` is unchanged and
                 each device runs the plan built for its B/devices

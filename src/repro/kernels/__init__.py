@@ -1,4 +1,4 @@
-"""Pallas TPU kernels (interpret-mode validated on CPU) + jnp templates.
+"""Pallas TPU kernels (compiled on TPU, interpreted elsewhere) + jnp templates.
 
 conv2d_nchwc — the paper's CONV template (Algorithm 1) blocked for the MXU;
 matmul_blocked — the LM-side GEMM instantiation of the same template;

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pltpu_compat import CompilerParams as _CompilerParams
 from repro.kernels.pltpu_compat import resolve_interpret
 
 NEG_INF = -1e30
@@ -84,7 +83,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                            *, causal: bool = True, window: int = 0,
                            bq: int = 128, bkv: int = 128,
-                           interpret: bool = None) -> jnp.ndarray:
+                           interpret: bool | None = None) -> jnp.ndarray:
     """q: (B, Hq, S, D); k, v: (B, Hkv, S, D); Hq % Hkv == 0.
     S must be divisible by bq and bkv (pad upstream if not).
 
@@ -135,7 +134,7 @@ def _flash_attention_jit(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pltpu.VMEM((bq, 1), jnp.float32),   # running denominator
             pltpu.VMEM((bq, d), jnp.float32),   # output accumulator
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

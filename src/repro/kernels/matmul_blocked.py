@@ -19,10 +19,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.epilogue import (EpilogueSpec, IDENTITY,
                                  apply_matmul_epilogue)
-from repro.kernels.pltpu_compat import CompilerParams as _CompilerParams
 from repro.kernels.pltpu_compat import resolve_interpret
 
 
@@ -47,8 +47,6 @@ class MatmulSchedule:
 
 def _mm_kernel(a_ref, b_ref, o_ref, *, nk: int, bm: int, bn: int,
                epilogue: EpilogueSpec, n_valid):
-    # program_id must be read at the kernel top level: inside a pl.when
-    # body the interpreter cannot lower it (jax 0.4.x)
     i, j, k = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(k == 0)
@@ -72,7 +70,7 @@ def _mm_kernel(a_ref, b_ref, o_ref, *, nk: int, bm: int, bn: int,
 
 def matmul_pallas(a: jnp.ndarray, b: jnp.ndarray, *,
                   schedule: MatmulSchedule = MatmulSchedule(),
-                  out_dtype=None, interpret: bool = None,
+                  out_dtype=None, interpret: bool | None = None,
                   epilogue: EpilogueSpec = IDENTITY,
                   n_valid: int = None) -> jnp.ndarray:
     """(M, K) @ (K, N) under the blocked template.
@@ -119,7 +117,7 @@ def _matmul_jit(a: jnp.ndarray, b: jnp.ndarray, *,
         ],
         out_specs=pl.BlockSpec((s.bm, s.bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)
@@ -128,7 +126,7 @@ def _matmul_jit(a: jnp.ndarray, b: jnp.ndarray, *,
 
 def matmul_padded(a: jnp.ndarray, b: jnp.ndarray, *,
                   schedule: MatmulSchedule = MatmulSchedule(),
-                  interpret: bool = None,
+                  interpret: bool | None = None,
                   epilogue: EpilogueSpec = IDENTITY) -> jnp.ndarray:
     """Pads M/K/N up to block multiples, runs the template, slices back —
     the wrapper the LM stack calls for arbitrary projection shapes.

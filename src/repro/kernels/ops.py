@@ -2,11 +2,11 @@
 
 Two execution paths per op, same template parameters:
 
-* ``*_pallas`` — the Pallas TPU kernel (interpret-mode on CPU), the target
-  artifact;
-* ``*_jnp``    — the identical loop nest expressed as strided slices + einsum
-  so XLA (CPU here, TPU in production as fallback) compiles it; the inference
-  engine uses this path for wall-clock runs in this container.
+* ``*_pallas`` — the Pallas TPU kernel: compiled on TPU, run by the Pallas
+  interpreter on backends without a Pallas lowering (``interpret=None``);
+* ``*_jnp``    — the identical loop nest expressed as strided slices + einsum,
+  compiled by XLA for whatever backend runs it; the engine's default path
+  (``use_pallas=False``).
 
 Both consume the NCHW[x]c / KCRS[x]c[y]k tensors the planner produces.
 """
@@ -332,7 +332,7 @@ def conv2d_blocked(x_blocked: jnp.ndarray, w_blocked: jnp.ndarray, *,
                    stride: int = 1, pad=0,
                    schedule: ConvSchedule | None = None,
                    use_pallas: bool = False,
-                   interpret: bool = True,
+                   interpret: bool | None = None,
                    w_prelaid: bool = False) -> jnp.ndarray:
     """Planner-facing entry point on blocked tensors.  On the jnp path the
     schedule's ``variant`` picks the lowering; the Pallas kernel has one
@@ -361,7 +361,7 @@ def conv2d_block_blocked(x_blocked: jnp.ndarray, w_blocked: jnp.ndarray,
                          epilogue: EpilogueSpec | None = None,
                          schedule: ConvSchedule | None = None,
                          use_pallas: bool = False,
-                         interpret: bool = True,
+                         interpret: bool | None = None,
                          w_prelaid: bool = False) -> jnp.ndarray:
     """Fused conv_block entry on blocked tensors (engine-facing).  ``scale``
     and ``shift`` are per-channel vectors pre-blocked to ``(Ko, oc_bn)``;
@@ -388,7 +388,8 @@ def conv2d_block_blocked(x_blocked: jnp.ndarray, w_blocked: jnp.ndarray,
 
 def conv2d(x_nchw: jnp.ndarray, w_kcrs: jnp.ndarray, *, stride: int = 1,
            pad=0, schedule: ConvSchedule,
-           use_pallas: bool = False, interpret: bool = True) -> jnp.ndarray:
+           use_pallas: bool = False,
+           interpret: bool | None = None) -> jnp.ndarray:
     """Convenience NCHW->NCHW entry: blocks inputs, runs the template,
     unblocks.  The engine never uses this (it keeps tensors blocked); tests
     and the quickstart do."""

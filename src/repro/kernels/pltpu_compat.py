@@ -1,16 +1,7 @@
-"""Compatibility shims for the Pallas TPU API surface.
-
-jax >= 0.4.34 renamed ``pltpu.TPUCompilerParams`` to
-``pltpu.CompilerParams``; every kernel imports the resolved class from
-here so the next rename is a one-line fix.
-"""
+"""Where a Pallas kernel runs compiled: ``resolve_interpret``."""
 from typing import Optional
 
 import jax
-from jax.experimental.pallas import tpu as pltpu
-
-CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
 
 # backends with a compiled Pallas lowering for these kernels; anything
 # else (cpu, the gpu triton path we don't target) runs the interpreter
@@ -27,7 +18,8 @@ def resolve_interpret(interpret: Optional[bool]) -> bool:
     ``interpret=True`` for determinism, and a TPU user can force the
     interpreter to debug a kernel.
 
-    Must be called *outside* ``jax.jit`` (it queries the backend).
+    It reads the default backend, never a traced value, so calling it while
+    a jitted caller traces is fine.
     """
     if interpret is None:
         return jax.default_backend() not in _COMPILED_PALLAS_BACKENDS

@@ -21,8 +21,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pltpu_compat import CompilerParams as _CompilerParams
+from repro.kernels.pltpu_compat import resolve_interpret
 
 
 def _ssd_intra_kernel(cc_ref, bc_ref, acum_ref, x_ref, o_ref):
@@ -42,13 +43,19 @@ def _ssd_intra_kernel(cc_ref, bc_ref, acum_ref, x_ref, o_ref):
                           ).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
 def ssd_intra_pallas(cc: jnp.ndarray, bc: jnp.ndarray, acum: jnp.ndarray,
-                     xd: jnp.ndarray, *, interpret: bool = True
+                     xd: jnp.ndarray, *, interpret: bool | None = None
                      ) -> jnp.ndarray:
     """cc, bc: (BC, Q, N) — per-(batch x chunk) C/B blocks (shared across
     heads); acum: (BC, H, Q) cumulative decay logs; xd: (BC, H, Q, P)
-    dt-weighted inputs.  Returns y_diag: (BC, H, Q, P)."""
+    dt-weighted inputs.  Returns y_diag: (BC, H, Q, P).  ``interpret=None``
+    compiles on TPU and interprets elsewhere."""
+    return _ssd_intra_jit(cc, bc, acum, xd,
+                          interpret=resolve_interpret(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _ssd_intra_jit(cc, bc, acum, xd, *, interpret: bool) -> jnp.ndarray:
     bcn, q, n = cc.shape
     _, h, _, p = xd.shape
     grid = (bcn, h)
@@ -63,7 +70,7 @@ def ssd_intra_pallas(cc: jnp.ndarray, bc: jnp.ndarray, acum: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((1, 1, q, p), lambda i, j: (i, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((bcn, h, q, p), xd.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(cc, bc, acum, xd)

@@ -6,8 +6,9 @@ by every entrypoint that wants multi-core execution:
 * :func:`configure_cpu_devices` — expose ``n`` host cores as JAX devices
   (``--xla_force_host_platform_device_count=n``) by *merging* into any
   existing ``XLA_FLAGS`` instead of clobbering it.  Must run before the
-  first JAX backend use; warns (never fails) when it is too late or when
-  ``n`` oversubscribes the host.  ``launch/dryrun.py`` / ``launch/perf.py``
+  first JAX backend use; warns (never fails) when ``n`` oversubscribes
+  the host.  It affects only the CPU backend: TPU chips are selected
+  from ``jax.devices()``.  ``launch/dryrun.py`` / ``launch/perf.py``
   route their 512 placeholder devices through here, and
   ``benchmarks/scaling_cores.py`` / ``launch/serve.py --devices`` use it
   for real data-parallel meshes.
@@ -27,7 +28,6 @@ by every entrypoint that wants multi-core execution:
 from __future__ import annotations
 
 import os
-import sys
 import warnings
 from typing import Dict, List, MutableMapping, Optional, Sequence, Tuple
 
@@ -72,21 +72,6 @@ def parse_xla_flag(flags: str, flag: str) -> Optional[str]:
     return None
 
 
-def _jax_backend_initialized() -> bool:
-    """Best-effort: has a JAX backend already been created (device count
-    locked)?  Importing jax alone does *not* initialize the backend, so
-    this peeks at the bridge's cache instead of calling ``jax.devices()``
-    (which would itself initialize it)."""
-    jax = sys.modules.get("jax")
-    if jax is None:
-        return False
-    try:
-        bridge = sys.modules.get("jax._src.xla_bridge")
-        return bool(getattr(bridge, "_backends", None))
-    except Exception:       # noqa: BLE001 — internals moved; assume not init
-        return False
-
-
 def configure_cpu_devices(n: int, *,
                           env: MutableMapping[str, str] = os.environ,
                           warn_oversubscribe: bool = True) -> int:
@@ -95,9 +80,8 @@ def configure_cpu_devices(n: int, *,
     Merges ``--xla_force_host_platform_device_count=n`` into
     ``env["XLA_FLAGS"]`` — existing user flags are preserved, an existing
     device-count assignment is replaced (never duplicated).  Must run
-    before the first JAX backend use; if the backend is already
-    initialized a warning is emitted (the flag will only affect child
-    processes).  ``n`` larger than the host's core count is allowed —
+    before the first JAX backend use: after it, the flag affects only
+    child processes.  ``n`` larger than the host's core count is allowed —
     placeholder-device dry-runs depend on it — but warns unless
     ``warn_oversubscribe=False``.  Returns ``n``.
     """
@@ -110,12 +94,6 @@ def configure_cpu_devices(n: int, *,
             f"requesting {n} CPU devices on a {total}-core host: devices "
             "beyond the core count time-share and will not scale "
             "(expected only for placeholder-device dry-runs)",
-            RuntimeWarning, stacklevel=2)
-    if env is os.environ and _jax_backend_initialized():
-        warnings.warn(
-            "configure_cpu_devices called after the JAX backend "
-            "initialized — the device count is already locked for this "
-            "process and the flag will only affect child processes",
             RuntimeWarning, stacklevel=2)
     env["XLA_FLAGS"] = merge_xla_flag(env.get("XLA_FLAGS", ""),
                                       DEVICE_COUNT_FLAG, n)

@@ -1,7 +1,7 @@
 """Serving driver: batched prefill + decode loop (the paper's kind of
 workload — latency-focused inference).
 
-Greedy-decodes a batch of synthetic prompts with a reduced config on CPU;
+Greedy-decodes a batch of synthetic prompts with a reduced config;
 at production scale the same prefill/decode_step functions are what the
 dry-run lowers onto the 256/512-chip meshes.
 
@@ -13,34 +13,42 @@ artifacts (manifest ``lm`` section, built with
 ``engine.compile(<LM config>, ...).save(dir)``) go load -> prewarm ->
 ``submit_stream`` with seq-bucketed prefill and streamed greedy decode.
 
-Multi-core serving: ``--devices D`` exposes D host cores as JAX devices
-*before* the backend initializes (``launch.cpu.configure_cpu_devices`` —
-required for sharded artifacts and for ``--workers`` replicas to land on
-distinct devices), ``--workers N`` runs N driver workers over one queue,
-and ``--pin-workers`` gives each worker its own CPU affinity set.  The
+Multi-device serving: ``--devices D`` serves on the first D of
+``jax.devices()`` — the chips of a TPU host — and fails if the process
+sees fewer; ``--workers N`` runs N driver workers over one queue, each
+worker's replica on its own device of those D.  On a CPU run
+(``JAX_PLATFORMS=cpu``) D host cores are first exposed as JAX devices
+(``launch.cpu.configure_cpu_devices``, before the backend initializes).
+``--pin-workers`` gives each worker its own CPU affinity set.  The
 allocator/threading env preset (``launch.cpu.apply_serving_env``:
 tcmalloc LD_PRELOAD detection, log/alloc-report hygiene — warn, never
 fail) is applied on every serve.
+
+The compile cache lives where ``launch.cache.enable_compile_cache``
+says (``JAX_COMPILATION_CACHE_DIR``, else ``<checkout>/.jax_cache``).
 
 Examples:
     PYTHONPATH=src python -m repro.launch.serve --arch mamba2-130m \
         --batch 4 --prompt-len 32 --gen 16
     PYTHONPATH=src python -m repro.launch.serve --artifact artifact/ \
-        --requests 50 --devices 4 --workers 4 --pin-workers
+        --requests 50 --devices 4 --workers 4
 """
 from __future__ import annotations
 
 import argparse
+import os
 import sys
 import time
 
+from repro.launch.cache import enable_compile_cache
 from repro.launch.cpu import apply_serving_env, configure_cpu_devices
 
-# --devices must take effect before the first jax import below locks the
-# backend: peek at argv here (only when this module IS the entrypoint —
-# `python -m repro.launch.serve` executes it as __main__), full parsing
-# stays in main().  Library callers configure devices themselves.
-if __name__ == "__main__":
+# on a CPU run, --devices host cores must exist before the first jax
+# import below locks the backend: peek at argv here (only when this
+# module IS the entrypoint — `python -m repro.launch.serve` executes it
+# as __main__), full parsing stays in main().  A TPU host's chips exist
+# already; serve_artifact selects them.
+if __name__ == "__main__" and os.environ.get("JAX_PLATFORMS") == "cpu":
     _early = argparse.ArgumentParser(add_help=False)
     _early.add_argument("--devices", type=int, default=None)
     _early_args, _ = _early.parse_known_args(sys.argv[1:])
@@ -58,7 +66,7 @@ from repro.models.lm import model                          # noqa: E402
 def serve_artifact(path: str, n_requests: int, *, max_batch: int = 8,
                    max_wait_ms: float = 2.0, max_queue: int = 64,
                    deadline_ms: float = None, workers: int = 1,
-                   pin=None, shed: str = "newest",
+                   devices: int = None, pin=None, shed: str = "newest",
                    retry_budget: int = 2, backoff_ms: float = 10.0,
                    watchdog_ms: float = None, show_health: bool = False,
                    dtype: str = None, trace: str = "uniform",
@@ -70,7 +78,9 @@ def serve_artifact(path: str, n_requests: int, *, max_batch: int = 8,
     ``QueueFullError``), and drain gracefully on shutdown.  The driver
     packs requests into the artifact's specialized batch sizes, so the
     whole run stays at zero schedule searches; ``workers > 1`` executes
-    batches concurrently through per-device program replicas.
+    batches concurrently through per-device program replicas, on the
+    first ``devices`` of ``jax.devices()`` when that is given (all of
+    them otherwise).
 
     Fault-tolerance knobs map straight onto ``AsyncServer``: ``shed``
     picks the overload policy, ``retry_budget``/``backoff_ms`` configure
@@ -93,6 +103,7 @@ def serve_artifact(path: str, n_requests: int, *, max_batch: int = 8,
 
     if n_requests < 1:
         raise ValueError(f"--requests must be >= 1, got {n_requests}")
+    devs = select_devices(devices)
     n_searches = search_calls()
     t0 = time.perf_counter()
     sess = InferenceSession.load(path)
@@ -128,7 +139,7 @@ def serve_artifact(path: str, n_requests: int, *, max_batch: int = 8,
                                 order="fifo" if trace == "uniform"
                                 else "edf")
     server = AsyncServer(sess, policy, max_queue=max_queue,
-                         workers=workers, pin=pin, shed=shed,
+                         workers=workers, devices=devs, pin=pin, shed=shed,
                          retry=RetryPolicy(budget=retry_budget,
                                            backoff_ms=backoff_ms),
                          watchdog_ms=watchdog_ms,
@@ -187,7 +198,7 @@ def serve_artifact(path: str, n_requests: int, *, max_batch: int = 8,
           f"dtype={sess.dtype} "
           f"load={t_load * 1e3:.0f} ms (zero search, zero re-binding) "
           f"buckets={sess.batch_sizes} devices={sess.devices} "
-          f"workers={workers}")
+          f"workers={workers} on {len(devs)} {devs[0].platform} device(s)")
     print(f"served {st.n_completed}/{n_requests} requests in "
           f"{st.n_batches} batches "
           f"(mean {st.rows_executed / max(st.n_batches, 1):.1f} rows, "
@@ -221,6 +232,19 @@ def serve_artifact(path: str, n_requests: int, *, max_batch: int = 8,
                   f"set {old}, on the measured histogram "
                   f"{dict(sorted(hist.items()))}")
     return out
+
+
+def select_devices(n: int = None):
+    """The first ``n`` of ``jax.devices()`` (all of them for None); fails
+    when the process sees fewer."""
+    devs = jax.devices()
+    if n is None:
+        return devs
+    if n < 1 or n > len(devs):
+        raise ValueError(
+            f"--devices {n} asked for, but this process sees {len(devs)} "
+            f"{devs[0].platform} device(s)")
+    return devs[:n]
 
 
 def serve_lm_artifact(path: str, n_requests: int, *, gen: int = 8,
@@ -349,9 +373,10 @@ def main(argv=None):
     ap.add_argument("--deadline-ms", type=float, default=None,
                     help="per-request deadline; queued past it fails typed")
     ap.add_argument("--devices", type=int, default=None,
-                    help="expose this many host cores as JAX devices "
-                         "(applied before backend init when this module "
-                         "is the entrypoint)")
+                    help="serve on the first this many of jax.devices() "
+                         "(the chips of a TPU host); fails if there are "
+                         "fewer.  With JAX_PLATFORMS=cpu, this many host "
+                         "cores are exposed as devices first")
     ap.add_argument("--workers", type=int, default=1,
                     help="driver worker threads (per-device program "
                          "replicas behind one queue)")
@@ -418,6 +443,7 @@ def main(argv=None):
                               max_queue=args.max_queue,
                               deadline_ms=args.deadline_ms,
                               workers=args.workers,
+                              devices=args.devices,
                               pin="auto" if args.pin_workers else None,
                               shed=args.shed,
                               retry_budget=args.retry_budget,
@@ -476,4 +502,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -56,7 +56,7 @@ def conv2d_nchw_direct(x: jnp.ndarray, w: jnp.ndarray, stride: int = 1,
 def conv2d(x: jnp.ndarray, w: jnp.ndarray, b: Optional[jnp.ndarray],
            layout: Layout, *, stride: int = 1, pad=0,
            groups: int = 1, schedule: Optional[ConvSchedule] = None,
-           use_pallas: bool = False, interpret: bool = True,
+           use_pallas: bool = False, interpret: Optional[bool] = None,
            w_prelaid: bool = False) -> jnp.ndarray:
     """``w`` (and ``b``) arrive pre-transformed for ``layout``:
     KCRS for NCHW, KCRS[x]c[y]k for blocked (panel-major when the engine
@@ -83,7 +83,7 @@ def conv_block(x: jnp.ndarray, w: jnp.ndarray,
                out_buf: Optional[jnp.ndarray] = None,
                schedule: Optional[ConvSchedule] = None,
                use_pallas: bool = False,
-               interpret: bool = True,
+               interpret: Optional[bool] = None,
                w_prelaid: bool = False) -> jnp.ndarray:
     """Fused CONV + composable epilogue (§3.1 operation fusion): per-channel
     affine (-> residual add) -> ReLU -> fused pooling, optionally stored at a

@@ -109,3 +109,22 @@ def test_model_flops_kinds():
     assert model_flops(cfg, "train", 2, 10) == 6.0 * n * 20
     assert model_flops(cfg, "prefill", 2, 10) == 2.0 * n * 20
     assert model_flops(cfg, "decode", 2, 10) == 2.0 * n * 2
+
+
+def test_peak_table_is_the_one_source():
+    """One peak table keyed by ``device_kind``: the planner's cost model
+    and the roofline read the same row, and an unlisted kind raises."""
+    from repro.analysis import roofline
+    from repro.core import cost
+    from repro.core.peaks import PEAKS, PLAN_TARGET, peaks
+
+    v5e = peaks("TPU v5 lite")
+    assert (v5e.bf16_flops, v5e.hbm_bytes_per_s) == (197e12, 819e9)
+    assert PLAN_TARGET in PEAKS
+    target = peaks(PLAN_TARGET)
+    assert cost.PEAK_FLOPS_BF16 == roofline.PEAK_FLOPS == target.bf16_flops
+    assert cost.HBM_BW == roofline.HBM_BW == target.hbm_bytes_per_s
+    assert cost.ICI_BW_PER_LINK == roofline.ICI_BW \
+        == target.ici_bytes_per_s_per_link
+    with pytest.raises(KeyError, match="no peaks for device kind 'cpu'"):
+        peaks("cpu")

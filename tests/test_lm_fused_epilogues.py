@@ -48,6 +48,20 @@ def _oracle(a, b, spec: EpilogueSpec):
     return out
 
 
+def _tail_tol(k: int, ref: np.ndarray) -> dict:
+    """Tolerance of the fused tail against the oracle.  The kernel sums the
+    length-``k`` contraction in ``bk``-wide blocks and the oracle in one
+    matmul; the two fp32 summation orders round apart by about
+    ``eps * sqrt(k)`` times the output's magnitude, so ``atol`` scales with
+    both (4x margin).  Outputs of order one (the softmax tails) stay at the
+    1e-5 floor."""
+    finite = np.abs(ref) < 1e30                  # masked entries excluded
+    rms = float(np.sqrt(np.mean(ref[finite] ** 2)))
+    eps = float(np.finfo(np.float32).eps)
+    return dict(rtol=TOL["rtol"],
+                atol=max(TOL["atol"], 4 * eps * np.sqrt(k) * rms))
+
+
 def _ab(m, k, n, seed=0):
     ka, kb = jax.random.split(jax.random.PRNGKey(seed))
     return (jax.random.normal(ka, (m, k), jnp.float32),
@@ -82,8 +96,8 @@ def test_fused_tail_matches_oracle(name, shape):
     spec = SPECS[name]
     got = matmul_padded(a, b, schedule=MatmulSchedule(bm=32, bk=32, bn=32),
                         epilogue=spec, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(_oracle(a, b, spec)),
-                               **TOL)
+    ref = np.asarray(_oracle(a, b, spec))
+    np.testing.assert_allclose(np.asarray(got), ref, **_tail_tol(k, ref))
     if spec.softmax:
         np.testing.assert_allclose(np.asarray(got).sum(-1),
                                    np.ones(m), **TOL)

@@ -145,7 +145,8 @@ def test_missing_devices_error_names_the_fix():
         pytest.skip("host already exposes multiple devices")
     g, shapes = _mini_net()
     sess = compile_session(g, shapes, devices=2, eager=False)
-    with pytest.raises(RuntimeError, match="configure_cpu_devices"):
+    with pytest.raises(RuntimeError, match=r"sees 1 \(cpu\); compile with "
+                                           r"devices <= 1"):
         sess.specialize(2)
 
 

@@ -10,6 +10,7 @@ pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st
 
 from repro.core import global_search, pbqp
+from repro.core.cost import conv_vmem_bytes, fits_vmem
 from repro.core.local_search import (ScheduleDatabase, local_search,
                                      roofline_runner)
 from repro.core.schedule import ConvSchedule, ConvWorkload, candidate_schedules
@@ -33,6 +34,34 @@ def test_local_search_ranked():
     assert costs == sorted(costs)
     assert res.best_for_layout(res.best.ic_bn, res.best.oc_bn).schedule \
         == res.best
+
+
+def test_vmem_footprint_counts_lane_padding():
+    """An RGB stem block (ic_bn=3) pads its 3 channels to the 128 lanes:
+    the footprint is that of a 128-channel plane, not a 3-channel one."""
+    stem = ConvWorkload(batch=1, in_channels=3, out_channels=64, height=224,
+                        width=224, kh=7, kw=7, stride=2, pad=3)
+    s = ConvSchedule(3, 64, 2, 112, True, "tap_stack")
+    # (230 rows, 2 column phases, 115 -> 120 sublanes, 3 -> 128 lanes) fp32,
+    # single-buffered: the only channel chunk
+    assert conv_vmem_bytes(stem, s) >= 230 * 2 * 120 * 128 * 4
+    assert fits_vmem(stem, s)
+
+
+def test_local_search_ranks_only_schedules_that_fit_vmem():
+    # the 300x300 stem of ssd-resnet-50: with its 3x3/2 max-pool fused no
+    # schedule fits, so all are ranked (planning never fails); without the
+    # pool, only the schedules that fit are
+    stem = dict(batch=1, in_channels=3, out_channels=64, height=300,
+                width=300, kh=7, kw=7, stride=2, pad=3)
+    big = ConvWorkload(**stem, fused_bn=True, fused_relu=True,
+                       fused_pool="max", pool_k=3, pool_stride=2, pool_pad=1)
+    assert not any(fits_vmem(big, s) for s in candidate_schedules(big))
+    assert len(local_search(big).ranked) == len(candidate_schedules(big))
+    wl = ConvWorkload(**stem)
+    fitting = [s for s in candidate_schedules(wl) if fits_vmem(wl, s)]
+    assert fitting and len(fitting) < len(candidate_schedules(wl))
+    assert {r.schedule for r in local_search(wl).ranked} == set(fitting)
 
 
 def test_schedule_database_roundtrip(tmp_path):
