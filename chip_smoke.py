@@ -12,17 +12,20 @@ Default (one chip, one process):
                warm both buckets, serve single-image requests through
                ``AsyncServer`` with dynamic batching; zero schedule
                searches on load -> serve; served logits checked against
-               the same plan and parameters on the host's CPU backend.
+               the same plan and parameters on the host's CPU backend,
+               and again at "highest" precision, bucket 1 called directly
+               and the largest bucket through the server.
 3. pallas   — the same model with ``use_pallas=True``: the Pallas conv
                kernel compiled (not interpreted), checked against phase 2
-               and, at "highest" precision, against the CPU reference.
+               and the CPU reference, and at "highest" precision against
+               the CPU reference.
 4. lm       — reduced ``qwen2-1.5b``: tokens streamed through
                ``AsyncServer.submit_stream`` equal ``LMSession.generate``.
 
 ``--four-chips`` runs only the multi-chip serving path: a batch-sharded
 ``compile(..., devices=4)`` artifact and ``AsyncServer(workers=4)`` with
-one replica per chip, each compared with a one-chip run of the same
-artifact.
+one replica per chip serving at "highest" precision, each compared with
+a one-chip run of the same artifact.
 
 The last line of standard output is ``{"ok": true, "device": {...}}``; a
 failed phase raises, so that line is never printed.  Weights and inputs
@@ -35,6 +38,7 @@ JAX's compile cache where ``repro.launch.cache`` says.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import os
 import shutil
@@ -56,9 +60,11 @@ DEFAULT_PRECISION_RTOL = 5e-2
 HIGHEST_PRECISION_RTOL = 1e-3
 # Random weights without normalisation grow ResNet-50's logits to ~1e4,
 # mostly the same for every input: the logits of different inputs differ
-# by ~2e-2 of their scale.  The default-precision tolerance cannot see a
-# result that ignored its input; the "highest" one can, as long as the
-# reference's inputs differ by more than this many times it.
+# by ~2e-2 of their scale.  A default-precision comparison therefore checks
+# numerics only: it cannot see a result that ignored its input, or a
+# server that returned another request's row.  Every path is also compared
+# at "highest" precision, whose tolerance can, as long as the reference's
+# inputs differ by more than this many times it (``check_spread``).
 MIN_SPREAD_OVER_RTOL = 4
 
 
@@ -170,20 +176,51 @@ def warm(sess, image: int, phase: str) -> None:
                    f"{time.perf_counter() - t0:.2f} s")
 
 
-def serve(sess, xs, workers: int = 1, devices=None):
+def serve(sess, xs, workers: int = 1, devices=None, bucket: int = None):
     """Serve ``xs`` one request each through ``AsyncServer`` with dynamic
-    batching; returns the outputs and the server's stats."""
+    batching, every batch in ``bucket`` when it is given; returns the
+    outputs and the server's stats."""
     import jax.numpy as jnp
 
     from repro.engine import AsyncServer, DynamicBatchPolicy
 
     policy = DynamicBatchPolicy(max_batch=max(sess.batch_sizes),
-                                max_wait_ms=2.0)
+                                max_wait_ms=2.0, fixed_bucket=bucket)
     with AsyncServer(sess, policy, max_queue=4 * len(xs), workers=workers,
                      devices=devices) as srv:
         futs = [srv.submit(jnp.asarray(x)) for x in xs]
         outs = [f.result(timeout=600) for f in futs]
     return outs, srv.stats
+
+
+@contextlib.contextmanager
+def precision_highest():
+    """"highest" matmul precision for the whole process: unlike the
+    thread-local ``jax.default_matmul_precision``, the server's worker
+    threads see it too."""
+    import jax
+
+    prev = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_default_matmul_precision", "highest")
+    try:
+        yield
+    finally:
+        jax.config.update("jax_default_matmul_precision", prev)
+
+
+def serve_highest(phase: str, sess, xs, bucket: int, **kw):
+    """``xs`` served at "highest" precision, every batch padded into
+    ``bucket``; returns the rows in request order and the stats."""
+    with precision_highest():
+        outs, st = serve(sess, xs, bucket=bucket, **kw)
+    check(st.n_completed == len(xs), f"served {st.n_completed}/{len(xs)}")
+    check(st.rows_executed + st.rows_padded == bucket * st.n_batches,
+          f"{st.n_batches} batches ran {st.rows_executed} + "
+          f"{st.rows_padded} padded rows, not all in bucket {bucket}")
+    log(phase, f"served {len(xs)} requests at precision=highest in "
+               f"{st.n_batches} batches, all in bucket {bucket} "
+               f"({st.rows_padded} padded rows)")
+    return outs, st
 
 
 def cpu_logits(model, xs):
@@ -199,11 +236,10 @@ def cpu_logits(model, xs):
 
 def highest(sess, xs):
     """``sess``'s logits for ``xs`` at "highest" matmul precision."""
-    import jax
     import jax.numpy as jnp
     import numpy as np
 
-    with jax.default_matmul_precision("highest"):
+    with precision_highest():
         return np.concatenate([np.asarray(sess.predict(jnp.asarray(x)))
                                for x in xs])
 
@@ -256,8 +292,16 @@ def resnet_phase(model: str, image: int, seed: int, n_requests: int,
     check_spread(phase, ref, HIGHEST_PRECISION_RTOL)
     compare(phase, "served vs CPU reference", served, ref,
             DEFAULT_PRECISION_RTOL)
-    compare(phase, "precision=highest vs CPU reference",
+    # the tolerance that separates inputs, on both programs: bucket 1
+    # called directly, and the largest bucket through the server, its
+    # requests padded in beside each other
+    compare(phase, "bucket 1 at precision=highest vs CPU reference",
             highest(loaded, xs[:n_ref]), ref, HIGHEST_PRECISION_RTOL)
+    big = max(loaded.batch_sizes)
+    outs, _ = serve_highest(phase, loaded, xs[:n_ref], big)
+    compare(phase, f"served in bucket {big} at precision=highest vs CPU "
+                   "reference", np.concatenate([np.asarray(o) for o in outs]),
+            ref, HIGHEST_PRECISION_RTOL)
     return xs[:n_ref], served, ref
 
 
@@ -284,6 +328,8 @@ def pallas_phase(model: str, image: int, seed: int, xs, jnp_logits,
                           for x in xs])
     compare(phase, "Pallas vs jnp templates (both on the chip)", got,
             jnp_logits, DEFAULT_PRECISION_RTOL)
+    compare(phase, "Pallas vs CPU reference", got, ref,
+            DEFAULT_PRECISION_RTOL)
     compare(phase, "Pallas at precision=highest vs CPU reference",
             highest(sess, xs), ref, HIGHEST_PRECISION_RTOL)
 
@@ -386,14 +432,23 @@ def four_chip_phase(model: str, image: int, seed: int, devs) -> None:
                    f"{np.asarray(yd).tobytes() == one8.tobytes()}")
         compare(phase, f"replica on chip {d.id} vs one chip", yd, one8,
                 HIGHEST_PRECISION_RTOL)
-    outs, st = serve(single, inputs(seed + 1, 64, image), workers=4,
-                     devices=chips)
+    reqs = inputs(seed + 1, 64, image)
+    outs, st = serve_highest(phase, single, reqs, 8, workers=4,
+                             devices=chips)
     out_devs = {d for o in outs for d in o.devices()}
-    log(phase, f"AsyncServer(workers=4): {st.n_completed} requests, "
-               f"batches per worker {dict(sorted(st.worker_batches.items()))}"
-               f", outputs on chips {sorted(d.id for d in out_devs)}")
-    check(st.n_completed == 64, f"served {st.n_completed}/64")
+    log(phase, f"AsyncServer(workers=4): batches per worker "
+               f"{dict(sorted(st.worker_batches.items()))}, outputs on "
+               f"chips {sorted(d.id for d in out_devs)}")
     check(out_devs == set(chips), f"outputs on {out_devs}, want {chips}")
+    # one chip, the same bucket-8 program, the same requests
+    want = highest(m1, [np.concatenate(reqs[i:i + 8])
+                        for i in range(0, len(reqs), 8)])
+    check_spread(phase, want, HIGHEST_PRECISION_RTOL)
+    got = np.concatenate([np.asarray(o) for o in outs])
+    log(phase, f"AsyncServer(workers=4) bit-identical to one chip: "
+               f"{got.tobytes() == want.tobytes()}")
+    compare(phase, "AsyncServer(workers=4) vs one chip, precision=highest",
+            got, want, HIGHEST_PRECISION_RTOL)
 
 
 def result_line(devs) -> str:

@@ -119,10 +119,26 @@ def conv_vmem_bytes(wl: ConvWorkload, s: ConvSchedule) -> int:
     return inp + ker + vecs + outp + scratch + res + buf
 
 
+# per-step working set past which conv_schedule_cost charges a spill
+SPILL_BYTES = 16 * 1024 * 1024
+
+
+def _working_set_bytes(wl: ConvWorkload, s: ConvSchedule) -> int:
+    """Untiled bytes one step of the blocked loop nest touches: the
+    (H_pad, W_pad, ic_bn) input slab, the weight block and the fp32
+    (oh_bn, OW, oc_bn) output rows."""
+    _, ow = wl.out_hw
+    b = wl.dtype_bytes
+    inp = (wl.height + 2 * wl.pad) * (wl.width + 2 * wl.pw) * s.ic_bn * b
+    ker = wl.kh * wl.kw * s.ic_bn * s.oc_bn * (1 if s.dtype == "int8" else b)
+    return inp + ker + s.oh_bn * ow * s.oc_bn * 4
+
+
 def fits_vmem(wl: ConvWorkload, s: ConvSchedule) -> bool:
     """Whether the Pallas kernel can stage schedule ``s`` within the VMEM
-    budget it asks the compiler for; the local search never ranks a
-    schedule that does not."""
+    budget it asks the compiler for; a local search for the kernel
+    (``local_search(..., pallas=True)``) never ranks a schedule that does
+    not."""
     return conv_vmem_bytes(wl, s) <= VMEM_BUDGET
 
 
@@ -140,6 +156,13 @@ def conv_schedule_cost(wl: ConvWorkload, s: ConvSchedule,
       tap_stack/patch_gemm materialize the input ``kh*kw`` times (write +
       GEMM read); scan carries the accumulator in the loop but copies a
       strided window per tap.
+
+    The Pallas kernel's schedules (``candidate_schedules(pallas=True)``)
+    are per_tap with ``ow_bn`` = OW, so compute is priced at the kernel's
+    own (OW × ic_bn) @ (ic_bn × oc_bn) tap GEMM.  Its accumulator stays in
+    VMEM, so per_tap's round-trip term overstates its memory time by the
+    same bytes for every schedule of a workload.  No device time has
+    checked this ranking yet (ROADMAP 3.1).
 
     The workload's fused-epilogue flags add the §3.1 epilogue traffic here,
     so the local search ranks schedules *with* their epilogue included
@@ -213,6 +236,10 @@ def conv_schedule_cost(wl: ConvWorkload, s: ConvSchedule,
         dtype_bytes=b)
     memory_s = (input_bytes + weight_bytes + output_bytes + variant_bytes
                 + epi_bytes) / HBM_BW
+    # schedules whose working set spills pay a heavy penalty (they would
+    # thrash HBM); the Pallas kernel's hard VMEM limit is fits_vmem
+    if _working_set_bytes(wl, s) > SPILL_BYTES:
+        memory_s *= 8.0
     return CostBreakdown(compute_s=compute_s, memory_s=memory_s)
 
 

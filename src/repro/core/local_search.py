@@ -22,6 +22,7 @@ from pathlib import Path
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 from repro.core.cost import CostBreakdown, conv_schedule_cost, fits_vmem
+from repro.core.peaks import VMEM_BUDGET
 from repro.core.schedule import ConvSchedule, ConvWorkload, candidate_schedules
 
 Runner = Callable[[ConvWorkload, ConvSchedule], float]
@@ -160,14 +161,20 @@ class LocalSearchResult:
 
 
 def local_search(wl: ConvWorkload, runner: Runner = roofline_runner,
-                 max_candidates: int = 0) -> LocalSearchResult:
+                 max_candidates: int = 0,
+                 pallas: bool = False) -> LocalSearchResult:
+    """Rank ``wl``'s candidate schedules by ``runner``.  ``pallas`` ranks
+    the Pallas conv kernel's space, and only the schedules whose blocks
+    fit the VMEM budget the kernel asks for; none fitting is an error."""
     SEARCH_COUNTERS["local_search"] += 1
-    # a schedule past the Pallas kernel's VMEM budget is ranked only when
-    # no schedule is within it (a plane too large for the kernel's
-    # layout): the jnp templates still run it, and the kernel's own
-    # compile decides whether it fits
-    cands = candidate_schedules(wl, max_candidates=max_candidates)
-    cands = [s for s in cands if fits_vmem(wl, s)] or cands
+    cands = candidate_schedules(wl, max_candidates=max_candidates,
+                                pallas=pallas)
+    if pallas:
+        cands = [s for s in cands if fits_vmem(wl, s)]
+        if not cands:
+            raise ValueError(
+                f"no schedule of {wl} fits the Pallas conv kernel's VMEM "
+                f"budget ({VMEM_BUDGET} bytes); plan it without use_pallas")
     scored = [RankedSchedule(s, runner(wl, s)) for s in cands]
     scored.sort(key=lambda r: (r.cost_s, r.schedule))
     return LocalSearchResult(workload=wl, ranked=scored)
@@ -176,7 +183,8 @@ def local_search(wl: ConvWorkload, runner: Runner = roofline_runner,
 def guided_local_search(wl: ConvWorkload, top_k: int = 6,
                         max_candidates: int = 0,
                         per_variant: int = 2,
-                        repeats: int = 3) -> LocalSearchResult:
+                        repeats: int = 3,
+                        pallas: bool = False) -> LocalSearchResult:
     """The paper's measure-on-target methodology, made affordable: the
     roofline model prunes the space, wall-clock measurement ranks the
     survivors.  Used by the --measured benchmarks on this host CPU.
@@ -198,7 +206,7 @@ def guided_local_search(wl: ConvWorkload, top_k: int = 6,
     final winner is deterministic instead of an OS-jitter coin flip."""
     SEARCH_COUNTERS["guided_local_search"] += 1
 
-    pruned = local_search(wl, roofline_runner, max_candidates)
+    pruned = local_search(wl, roofline_runner, max_candidates, pallas)
     short: List[ConvSchedule] = []
     seen = set()
 
@@ -249,7 +257,7 @@ def guided_local_search(wl: ConvWorkload, top_k: int = 6,
 # repeating search for the same convolution in different models")
 # ---------------------------------------------------------------------------
 
-def _wl_key(wl: ConvWorkload) -> str:
+def _wl_key(wl: ConvWorkload, pallas: bool = False) -> str:
     key = (f"n{wl.batch}_c{wl.in_channels}_k{wl.out_channels}"
            f"_h{wl.height}_w{wl.width}_r{wl.kh}s{wl.kw}"
            f"_st{wl.stride}_p{wl.pad}_g{wl.groups}")
@@ -269,6 +277,8 @@ def _wl_key(wl: ConvWorkload) -> str:
         key += f"_cat{wl.concat_offset}of{wl.concat_total}"
     if wl.quantize:  # int8-eligible searches rank a larger candidate space
         key += "_q8"
+    if pallas:  # the Pallas kernel's space (candidate_schedules(pallas=True))
+        key += "_pallas"
     return key
 
 
@@ -289,17 +299,19 @@ class ScheduleDatabase:
             self._load()
 
     def search(self, wl: ConvWorkload, runner: Runner = roofline_runner,
-               max_candidates: int = 0) -> LocalSearchResult:
-        key = _wl_key(wl)
+               max_candidates: int = 0,
+               pallas: bool = False) -> LocalSearchResult:
+        key = _wl_key(wl, pallas)
         if key not in self._mem:
-            self._mem[key] = local_search(wl, runner, max_candidates)
+            self._mem[key] = local_search(wl, runner, max_candidates, pallas)
             if self.path:
                 self._save()
         return self._mem[key]
 
     def search_measured(self, wl: ConvWorkload, top_k: int = 6,
                         per_variant: int = 2,
-                        repeats: int = 3) -> LocalSearchResult:
+                        repeats: int = 3,
+                        pallas: bool = False) -> LocalSearchResult:
         """Memoized guided (roofline-pruned, wall-clock-ranked) search.  A
         database pre-populated through this method hands the planner measured
         ``(variant, blocking)`` winners — ``plan(db=...)`` reuses the entry
@@ -308,13 +320,14 @@ class ScheduleDatabase:
         *analytical* (roofline costs masquerading as measured ms corrupted
         winners otherwise) or was measured with a *shallower* budget (a
         smoke-run database must not silently cap a full search)."""
-        key = _wl_key(wl)
+        key = _wl_key(wl, pallas)
         have = self._mem.get(key)
         if (have is None or not have.measured
                 or have.search_budget[0] < top_k
                 or have.search_budget[1] < per_variant):
             self._mem[key] = guided_local_search(
-                wl, top_k=top_k, per_variant=per_variant, repeats=repeats)
+                wl, top_k=top_k, per_variant=per_variant, repeats=repeats,
+                pallas=pallas)
             if self.path:
                 self._save()
         return self._mem[key]

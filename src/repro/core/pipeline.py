@@ -337,6 +337,7 @@ class PipelineState:
     runner: Runner = roofline_runner
     tuning: str = "roofline"            # "roofline" | "cached" | "measured"
     quantize: bool = False              # enumerate int8 schedules per conv
+    pallas: bool = False                # plan for the Pallas conv kernel
     transform_bw: Optional[float] = None
     search_budget: Tuple[int, int, int] = (6, 2, 3)  # top_k, per_variant, reps
     locals_: Dict[str, LocalSearchResult] = dataclasses.field(
@@ -399,7 +400,9 @@ class LocalTune(Pass):
     ``"roofline"``/``"cached"`` rank with the analytical model (``cached``
     differs only in intent — the database is expected to arrive
     pre-populated, e.g. from a saved artifact, so nothing new is searched);
-    ``"measured"`` runs the guided roofline-pruned wall-clock search."""
+    ``"measured"`` runs the guided roofline-pruned wall-clock search.  The
+    state's ``pallas`` searches the Pallas conv kernel's schedule space
+    (only schedules that fit its VMEM budget) instead of the jnp one."""
 
     name = "local-tune"
 
@@ -412,9 +415,10 @@ class LocalTune(Pass):
                 top_k, per_variant, repeats = state.search_budget
                 res = state.db.search_measured(
                     wl, top_k=top_k, per_variant=per_variant,
-                    repeats=repeats)
+                    repeats=repeats, pallas=state.pallas)
             else:
-                res = state.db.search(wl, runner=state.runner)
+                res = state.db.search(wl, runner=state.runner,
+                                      pallas=state.pallas)
             state.locals_[node.name] = res
         return {"n_convs": len(state.locals_),
                 "n_new_workloads": len(state.db) - n_before,
@@ -568,6 +572,7 @@ class Pipeline:
             runner: Runner = roofline_runner,
             tuning: str = "roofline",
             quantize: bool = False,
+            pallas: bool = False,
             transform_bw: Optional[float] = None,
             search_budget: Tuple[int, int, int] = (6, 2, 3)) -> Plan:
         # transform_bw: bytes/s the *execution host* moves a layout
@@ -584,7 +589,7 @@ class Pipeline:
                               db=db if db is not None else ScheduleDatabase(),
                               runner=runner,
                               tuning=tuning, quantize=quantize,
-                              transform_bw=transform_bw,
+                              pallas=pallas, transform_bw=transform_bw,
                               search_budget=search_budget)
         t_start = time.perf_counter()
         pass_reports: List[PassReport] = []

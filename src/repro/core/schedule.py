@@ -197,9 +197,14 @@ def _channel_candidates(channels: int) -> List[int]:
 
 
 def candidate_schedules(wl: ConvWorkload, max_candidates: int = 0,
-                        ) -> List[ConvSchedule]:
+                        pallas: bool = False) -> List[ConvSchedule]:
     """Enumerate the search space of §3.3.1: all channel-factor splits ×
     ow blocking × unroll choice × lowering variant, deduped.
+
+    ``pallas`` enumerates the space of the Pallas conv kernel
+    (``kernels/conv2d_nchwc.py``) instead: its M-tile is the whole output
+    row and it issues one GEMM per tap, so ``ow_bn`` is pinned to OW and
+    the variant to ``per_tap`` — the jnp lowering axes do not exist there.
 
     ``max_candidates`` > 0 truncates the (ic-major) enumeration — only
     useful for tests; the full space is bounded (≤ 6*6*4*2*2*4 tuples) and
@@ -218,6 +223,9 @@ def candidate_schedules(wl: ConvWorkload, max_candidates: int = 0,
         ocs = [f for f in ocs
                if wl.concat_offset % f == 0 and wl.concat_total % f == 0]
     ows = [f for f in _OW_CANDIDATES if ow % f == 0] or [1]
+    variants = VARIANTS
+    if pallas:
+        ows, variants = [ow], ("per_tap",)
     if wl.fused_pool:
         # fused pooling reduces over the whole conv plane before the store,
         # so the output blocking collapses to whole-plane rows — the pooled
@@ -230,7 +238,7 @@ def candidate_schedules(wl: ConvWorkload, max_candidates: int = 0,
     for ic_bn, oc_bn, ow_bn in itertools.product(ics[:6], ocs[:6], ows[:4]):
         for oh_bn in ohs[:2]:
             for unroll in (True, False):
-                for variant in VARIANTS:
+                for variant in variants:
                     out.append(ConvSchedule(ic_bn, oc_bn, ow_bn, oh_bn,
                                             unroll, variant))
                     if wl.quantize and variant in INT8_VARIANTS:

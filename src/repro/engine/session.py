@@ -385,7 +385,7 @@ class InferenceSession:
                 self._graph, self._shapes_for(batch // self.devices),
                 db=self.db,
                 tuning=self.tuning, quantize=(self.dtype == "int8"),
-                transform_bw=self.transform_bw,
+                pallas=self.use_pallas, transform_bw=self.transform_bw,
                 search_budget=self.search_budget)
             if (plan.report is not None
                     and plan.report.transform_bw is not None):

@@ -100,6 +100,13 @@ def configure_cpu_devices(n: int, *,
     return n
 
 
+def cpu_platform_allowed(env: MutableMapping[str, str] = os.environ
+                         ) -> bool:
+    """Whether JAX may run this process on its CPU backend:
+    ``JAX_PLATFORMS`` unset, or naming no platform but cpu."""
+    return set(env.get("JAX_PLATFORMS", "").split(",")) <= {"", "cpu"}
+
+
 def configured_device_count(env: MutableMapping[str, str] = os.environ
                             ) -> Optional[int]:
     """The device count currently forced in ``env``, or None."""

@@ -16,9 +16,10 @@ artifacts (manifest ``lm`` section, built with
 Multi-device serving: ``--devices D`` serves on the first D of
 ``jax.devices()`` — the chips of a TPU host — and fails if the process
 sees fewer; ``--workers N`` runs N driver workers over one queue, each
-worker's replica on its own device of those D.  On a CPU run
-(``JAX_PLATFORMS=cpu``) D host cores are first exposed as JAX devices
-(``launch.cpu.configure_cpu_devices``, before the backend initializes).
+worker's replica on its own device of those D.  Unless ``JAX_PLATFORMS``
+names a platform other than cpu, D host cores are first exposed as JAX
+CPU devices (``launch.cpu.configure_cpu_devices``, before the backend
+initializes), so a CPU-only host serves on them.
 ``--pin-workers`` gives each worker its own CPU affinity set.  The
 allocator/threading env preset (``launch.cpu.apply_serving_env``:
 tcmalloc LD_PRELOAD detection, log/alloc-report hygiene — warn, never
@@ -36,19 +37,21 @@ Examples:
 from __future__ import annotations
 
 import argparse
-import os
 import sys
 import time
 
 from repro.launch.cache import enable_compile_cache
-from repro.launch.cpu import apply_serving_env, configure_cpu_devices
+from repro.launch.cpu import (apply_serving_env, configure_cpu_devices,
+                              cpu_platform_allowed)
 
 # on a CPU run, --devices host cores must exist before the first jax
 # import below locks the backend: peek at argv here (only when this
 # module IS the entrypoint — `python -m repro.launch.serve` executes it
 # as __main__), full parsing stays in main().  A TPU host's chips exist
-# already; serve_artifact selects them.
-if __name__ == "__main__" and os.environ.get("JAX_PLATFORMS") == "cpu":
+# already; serve_artifact selects them.  Unless JAX_PLATFORMS names
+# another platform the host cores are exposed: the flag shapes only the
+# CPU backend, which a TPU host's serving does not use.
+if __name__ == "__main__" and cpu_platform_allowed():
     _early = argparse.ArgumentParser(add_help=False)
     _early.add_argument("--devices", type=int, default=None)
     _early_args, _ = _early.parse_known_args(sys.argv[1:])
@@ -375,8 +378,9 @@ def main(argv=None):
     ap.add_argument("--devices", type=int, default=None,
                     help="serve on the first this many of jax.devices() "
                          "(the chips of a TPU host); fails if there are "
-                         "fewer.  With JAX_PLATFORMS=cpu, this many host "
-                         "cores are exposed as devices first")
+                         "fewer.  Unless JAX_PLATFORMS names a platform "
+                         "other than cpu, this many host cores are "
+                         "exposed as CPU devices first")
     ap.add_argument("--workers", type=int, default=1,
                     help="driver worker threads (per-device program "
                          "replicas behind one queue)")

@@ -86,7 +86,46 @@ def test_resnet_and_lm_phases_tiny(tmp_path, monkeypatch, capsys):
     assert "zero schedule searches on load -> serve" in out
     assert "spread across inputs" in out
     assert "served 10 requests" in out
+    assert "all in bucket 8" in out
     assert "equal to LMSession.generate" in out
+
+
+def test_resnet_phase_fails_a_server_that_swaps_rows(tmp_path, monkeypatch):
+    """The served bucket-8 program is held to the tolerance that separates
+    inputs: a server handing each request another request's row fails."""
+    monkeypatch.setattr(chip_smoke, "OUT", tmp_path)
+    real = chip_smoke.serve
+
+    def swapping(sess, xs, **kw):
+        outs, st = real(sess, xs, **kw)
+        return (outs[::-1] if kw.get("bucket") else outs), st
+
+    monkeypatch.setattr(chip_smoke, "serve", swapping)
+    with pytest.raises(RuntimeError, match="served in bucket 8 .*rel err"):
+        chip_smoke.resnet_phase("resnet-18", 32, seed=0, n_requests=4,
+                                n_ref=2)
+
+
+def test_four_chip_phase_on_four_host_devices(tmp_path):
+    """``--four-chips``' phase at a tiny size on four host CPU devices:
+    the sharded bucket, the replicas and the four-worker server each
+    against one device."""
+    code = (
+        "import sys, jax\n"
+        f"sys.path.insert(0, {str(ROOT)!r})\n"
+        "import chip_smoke\n"
+        "from pathlib import Path\n"
+        f"chip_smoke.OUT = Path({str(tmp_path)!r})\n"
+        "chip_smoke.four_chip_phase('resnet-18', 32, 0, jax.devices())\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=str(ROOT / "src"),
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert "AsyncServer(workers=4) vs one chip, precision=highest" \
+        in proc.stdout
+    assert "outputs on chips [0, 1, 2, 3]" in proc.stdout
 
 
 # ---------------------------------------------------------------------------
@@ -144,6 +183,19 @@ def test_serve_select_devices():
     assert select_devices(1) == devs[:1]
     with pytest.raises(ValueError, match=f"sees {len(devs)} cpu"):
         select_devices(len(devs) + 1)
+
+
+@pytest.mark.parametrize("platforms, allowed", [
+    (None, True), ("", True), ("cpu", True), ("tpu", False),
+    ("tpu,cpu", False)])
+def test_serve_exposes_host_cores_unless_another_platform(platforms,
+                                                          allowed):
+    """``--devices N`` forces N host CPU devices where JAX may run on the
+    CPU: ``JAX_PLATFORMS`` unset (a CPU-only host) or cpu alone."""
+    from repro.launch.cpu import cpu_platform_allowed
+
+    env = {} if platforms is None else {"JAX_PLATFORMS": platforms}
+    assert cpu_platform_allowed(env) is allowed
 
 
 def test_result_line_is_the_contract():

@@ -49,19 +49,41 @@ def test_vmem_footprint_counts_lane_padding():
 
 
 def test_local_search_ranks_only_schedules_that_fit_vmem():
-    # the 300x300 stem of ssd-resnet-50: with its 3x3/2 max-pool fused no
-    # schedule fits, so all are ranked (planning never fails); without the
-    # pool, only the schedules that fit are
+    # the 300x300 stem of ssd-resnet-50: for the Pallas kernel, without its
+    # pool only the schedules that fit are ranked; with its 3x3/2 max-pool
+    # fused none fits, which is an error.  The jnp templates stage nothing
+    # in the kernel's VMEM: their search ranks every candidate
     stem = dict(batch=1, in_channels=3, out_channels=64, height=300,
                 width=300, kh=7, kw=7, stride=2, pad=3)
+    wl = ConvWorkload(**stem)
+    space = candidate_schedules(wl, pallas=True)
+    fitting = [s for s in space if fits_vmem(wl, s)]
+    assert fitting and len(fitting) < len(space)
+    ranked = {r.schedule for r in local_search(wl, pallas=True).ranked}
+    assert ranked == set(fitting)
+    assert len(local_search(wl).ranked) == len(candidate_schedules(wl))
     big = ConvWorkload(**stem, fused_bn=True, fused_relu=True,
                        fused_pool="max", pool_k=3, pool_stride=2, pool_pad=1)
-    assert not any(fits_vmem(big, s) for s in candidate_schedules(big))
+    assert not any(fits_vmem(big, s)
+                   for s in candidate_schedules(big, pallas=True))
+    with pytest.raises(ValueError, match="VMEM budget"):
+        local_search(big, pallas=True)
     assert len(local_search(big).ranked) == len(candidate_schedules(big))
-    wl = ConvWorkload(**stem)
-    fitting = [s for s in candidate_schedules(wl) if fits_vmem(wl, s)]
-    assert fitting and len(fitting) < len(candidate_schedules(wl))
-    assert {r.schedule for r in local_search(wl).ranked} == set(fitting)
+
+
+def test_pallas_space_is_the_kernels():
+    """The Pallas kernel's M-tile is the whole output row and it runs one
+    GEMM per tap: its space has no ow_bn or variant axis, and the database
+    keys its rankings apart from the jnp templates'."""
+    _, ow = WL.out_hw
+    space = candidate_schedules(WL, pallas=True)
+    assert {(s.ow_bn, s.variant) for s in space} == {(ow, "per_tap")}
+    assert {(s.ic_bn, s.oc_bn) for s in space} == {
+        (s.ic_bn, s.oc_bn) for s in candidate_schedules(WL)}
+    db = ScheduleDatabase()
+    jnp_best, pallas_best = db.search(WL).best, db.search(WL, pallas=True).best
+    assert len(db) == 2 and pallas_best in space
+    assert db.search(WL).best == jnp_best
 
 
 def test_schedule_database_roundtrip(tmp_path):
