@@ -50,7 +50,9 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, List, Optional
+import threading
+from concurrent.futures import ThreadPoolExecutor
+from typing import Any, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -264,6 +266,15 @@ def _eval_node(node, lay: Layout, schedule, use_pallas: bool,
     raise NotImplementedError(node.op)
 
 
+def _scoped(name: str, fn):
+    """``fn`` traced inside ``jax.named_scope(name)``: trace-time metadata
+    only, so it holds under ``jax.jit`` in both dispatch modes."""
+    def call(*args):
+        with jax.named_scope(name):
+            return fn(*args)
+    return call
+
+
 def _device_mesh(devices: int):
     """1-D ("data",) mesh over the first ``devices`` of ``jax.devices()``;
     fails when the process sees fewer."""
@@ -295,6 +306,8 @@ class CompiledModel:
         use_pallas, interpret = self.use_pallas, self.interpret
         topo = structure.graph.topo_order()
         self._replicas: Dict[Any, "_DeviceReplica"] = {}
+        self._warmed: set = set()        # (device, input shape, dtype)
+        self._warm_lock = threading.Lock()
 
         if self.dispatch not in ("whole", "op"):
             raise ValueError(f"unknown dispatch mode {self.dispatch!r}")
@@ -305,9 +318,11 @@ class CompiledModel:
                              "whole-graph dispatch; per-node dispatch "
                              "would materialize every intermediate "
                              "across the mesh")
-        fns = {n.name: functools.partial(
+        # each node's operations carry its name (``jax.named_scope``) in
+        # their HLO ``op_name``, so a device trace can be read per node
+        fns = {n.name: _scoped(n.name, functools.partial(
                    _eval_node, n, structure.layouts[n.name],
-                   structure.schedules.get(n.name), use_pallas, interpret)
+                   structure.schedules.get(n.name), use_pallas, interpret))
                for n in topo if n.op != "input"}
         if self.dispatch == "op":
             # graph-runtime dispatch: one XLA executable per node, compiled
@@ -385,6 +400,25 @@ class CompiledModel:
             rep = _DeviceReplica(self, device)
             self._replicas[device] = rep
         return rep
+
+    def warm_replicas(self, devices: Sequence, x: jnp.ndarray) -> None:
+        """Compiles the replicas on ``devices`` for inputs like ``x`` all at
+        once, one thread a device, by running each on ``x``: XLA compiles
+        with the interpreter lock released, so N replicas cost about the
+        time of one where each would otherwise compile on its first
+        batch.  Devices already warmed for ``x``'s shape are skipped; a
+        single one is left to compile on its first call."""
+        key = (tuple(x.shape), str(x.dtype))
+        if all((d, key) in self._warmed for d in devices):
+            return
+        with self._warm_lock:
+            reps = [self.replica(d) for d in dict.fromkeys(devices)
+                    if (d, key) not in self._warmed]
+            if len(reps) > 1:
+                with ThreadPoolExecutor(len(reps)) as pool:
+                    list(pool.map(lambda r: jax.block_until_ready(
+                        r.predict(x)), reps))
+            self._warmed.update((d, key) for d in devices)
 
 
 class _DeviceReplica:

@@ -99,6 +99,22 @@ deterministic :class:`~repro.engine.faults.FaultInjector` exercises each):
   unhealthy/restarted, retry/shed/crash counters) for external probes;
   the same counters ride in ``ServingStats.to_json``.
 
+Spans: each worker's cycle is written into the profiler's own trace with
+``jax.profiler.TraceAnnotation`` (one inactive check when no profiler
+session runs; the batch span's args are computed only while one does).
+``serving.idle`` (arg ``worker``) covers the wait for a formable batch;
+``serving.batch`` (args ``worker``, ``seq``, ``requests``, ``rows``,
+``bucket``, and ``wait_us_sum`` / ``wait_us_max``: µs from each
+request's submit to the batch's formation) covers one batch from
+formation to its stats update, with the children ``serving.gather``
+(concatenate and pad), ``serving.dispatch`` (choose the program and call
+it), ``serving.device_wait`` (``block_until_ready``) and
+``serving.scatter`` (slice each request's rows, resolve futures — the
+clients' callbacks run here — and count the batch); the slice that drops
+the padded rows lies between the last two.  A streamed generation gets
+``serving.batch`` alone.  The server also holds the collector-pause hook
+of ``engine/telemetry.py`` (``runtime.gc`` spans, ``health()["gc"]``).
+
 Tests drive the scheduling deterministically: construct with
 ``autostart=False`` and a fake ``clock``, then pump :meth:`AsyncServer.step`
 (and :meth:`AsyncServer.supervise`) by hand — no sleeps anywhere in the
@@ -107,6 +123,7 @@ suite.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import queue
 import threading
@@ -116,12 +133,14 @@ from typing import Callable, Deque, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.engine.faults import FaultInjector, InjectedWorkerCrash
 from repro.engine.supervision import (HeartbeatMonitor, RetryPolicy,
                                       SHED_POLICIES, StragglerMitigator,
                                       StragglerPolicy, choose_shed_victim)
-from repro.engine.telemetry import SizeHistogram, StreamingQuantiles
+from repro.engine.telemetry import (GC_PAUSES, SizeHistogram,
+                                    StreamingQuantiles, gc_pauses)
 from repro.engine.traffic import DEFAULT_PRIORITY, priority_rank
 
 
@@ -196,6 +215,20 @@ def _slice_rows(y, a: int, b: int):
     if isinstance(y, tuple):
         return tuple(t[a:b] for t in y)
     return y[a:b]
+
+
+def _batch_span(batch, worker: int, seq: int, rows: int, bucket: int,
+                formed: float):
+    """The ``serving.batch`` span of one batch formed at ``formed``; its
+    args (the queue waits among them) are computed only while a profiler
+    session records."""
+    if not TraceAnnotation.is_enabled():
+        return contextlib.nullcontext()
+    waits = [formed - r.t_submit for r in batch]
+    return TraceAnnotation("serving.batch", worker=worker, seq=seq,
+                           requests=len(batch), rows=rows, bucket=bucket,
+                           wait_us_sum=round(sum(waits) * 1e6),
+                           wait_us_max=round(max(waits) * 1e6))
 
 
 def padded_predict(session, x: jnp.ndarray, bucket: Optional[int] = None):
@@ -659,6 +692,8 @@ class AsyncServer:
             if watchdog_ms is not None and workers > 1 else None)
         self._supervisor: Optional[threading.Thread] = None
         self._stop_supervisor = threading.Event()
+        GC_PAUSES.acquire()
+        self._holds_gc_hook = True      # until close() releases it
         if autostart:
             for i in range(workers):
                 self._threads[i] = self._spawn_worker(i, gen=0)
@@ -1007,15 +1042,20 @@ class AsyncServer:
             t = d if t is None else min(t, d)
         return t
 
-    def _model_for(self, bucket: int, worker: int):
+    def _model_for(self, bucket: int, worker: int, x):
         """The executable this worker runs ``bucket`` through: the shared
         specialization for worker 0 (and single-worker servers), a
         same-program replica committed to device ``worker % D`` for the
-        rest — identical numerics, concurrent execution."""
+        rest — identical numerics, concurrent execution.  The first batch
+        ``x`` at a bucket compiles the replicas of every worker's device
+        at once (``CompiledModel.warm_replicas``), so no other worker
+        stalls on a compile of its own later."""
         m = self.session.specialize(bucket)
         if self.workers > 1 and getattr(m, "devices", 1) == 1:
             devs = self._devices or jax.devices()
             if len(devs) > 1:
+                m.warm_replicas([devs[w % len(devs)]
+                                 for w in range(self.workers)], x)
                 return m.replica(devs[worker % len(devs)])
         return m
 
@@ -1063,67 +1103,83 @@ class AsyncServer:
                 self._pending.appendleft(r)
             self._cond.notify_all()
 
-    def _execute(self, batch: List[Request], worker: int = 0,
-                 seq: Optional[int] = None) -> None:
+    def _bucket(self, rows: int) -> int:
+        """The batch size ``rows`` packed rows execute at: the pinned
+        bucket, else the nearest specialized one, else ``rows`` itself
+        (on-demand re-specialization, serialized by the session lock;
+        ``_cap()`` already rejected this for frozen sessions)."""
+        bucket = getattr(self.policy, "fixed_bucket", None)
+        if bucket is None:
+            bucket = nearest_bucket(rows, self.session.batch_sizes)
+        return rows if bucket is None else bucket
+
+    def _execute(self, batch: List[Request], worker: int, seq: int,
+                 formed: float) -> None:
+        """Run one batch formed at ``formed`` (server clock) and resolve
+        its requests, inside a ``serving.batch`` span whose args say
+        which batch it was and how long its requests queued (µs from
+        submit to formation), with a span for each step."""
         rows = sum(r.rows for r in batch)
-        try:
-            if self.faults is not None and seq is not None:
-                self.faults.fire(worker, seq, self._sleep)
-            if isinstance(batch[0], StreamRequest):
-                # streams execute alone (enforced by _form_locked): run
-                # the generation, tokens flowing to the client as each
-                # decode step lands; the full array resolves the future
-                r = batch[0]
-                bucket = rows            # no padding on the LM path
-                y = self.session.generate(r.x, r.max_new_tokens,
-                                          on_token=r.stream.push)
-            else:
-                xs = batch[0].x if len(batch) == 1 else \
-                    jnp.concatenate([r.x for r in batch])
-                bucket = getattr(self.policy, "fixed_bucket", None)
-                if bucket is None:
-                    bucket = nearest_bucket(rows, self.session.batch_sizes)
-                if bucket is None:
-                    # on-demand re-specialization (session lock serializes
-                    # the planner); _cap() already rejected this for frozen
-                    # sessions
-                    bucket = rows
-                m = self._model_for(bucket, worker)
-                y = m.predict(pad_rows(xs, bucket))
-                y = jax.block_until_ready(y)
-                y = _slice_rows(y, 0, rows)
-        except BaseException as e:      # noqa: BLE001 — retry or fail typed
-            self._fail_or_requeue(batch, e, worker=worker)
-            if isinstance(e, InjectedWorkerCrash):
-                raise WorkerCrashError(str(e)) from e
-            return
-        done = self._clock()
-        off = 0
-        n_ok = 0
-        lats = []
-        for r in batch:
-            if self._resolve(r.future, _slice_rows(y, off, off + r.rows)):
-                n_ok += 1
-                lats.append((done - r.t_submit, r.priority))
-            off += r.rows
-        with self._cond:
-            self._stats.n_batches += 1
-            self._stats.rows_executed += rows
-            self._stats.rows_padded += bucket - rows
-            self._stats.batch_hist.add(rows)
-            self._stats.n_completed += n_ok
-            for lat, prio in lats:
-                self._stats.record_latency(lat, prio)
-            self._stats.worker_batches[worker] = \
-                self._stats.worker_batches.get(worker, 0) + 1
-            # the batch leaves flight in the same locked section that
-            # counts it completed, so no snapshot can observe requests
-            # both completed and in flight (the callers' ``finally``
-            # removal stays as an identity-checked backstop for the
-            # watchdog-requeue path)
-            if self._inflight.get(worker) is batch:
-                del self._inflight[worker]
-            self._cond.notify_all()
+        stream = isinstance(batch[0], StreamRequest)
+        bucket = rows if stream else self._bucket(rows)  # no LM padding
+        with _batch_span(batch, worker, seq, rows, bucket, formed):
+            try:
+                if self.faults is not None:
+                    self.faults.fire(worker, seq, self._sleep)
+                if stream:
+                    # streams execute alone (enforced by _form_locked):
+                    # run the generation, tokens flowing to the client as
+                    # each decode step lands; the full array resolves the
+                    # future
+                    r = batch[0]
+                    y = self.session.generate(r.x, r.max_new_tokens,
+                                              on_token=r.stream.push)
+                else:
+                    with TraceAnnotation("serving.gather"):
+                        xs = batch[0].x if len(batch) == 1 else \
+                            jnp.concatenate([r.x for r in batch])
+                        xs = pad_rows(xs, bucket)
+                    with TraceAnnotation("serving.dispatch"):
+                        y = self._model_for(bucket, worker,
+                                            xs).predict(xs)
+                    with TraceAnnotation("serving.device_wait"):
+                        y = jax.block_until_ready(y)
+                    y = _slice_rows(y, 0, rows)
+            except BaseException as e:  # noqa: BLE001 — retry or fail typed
+                self._fail_or_requeue(batch, e, worker=worker)
+                if isinstance(e, InjectedWorkerCrash):
+                    raise WorkerCrashError(str(e)) from e
+                return
+            # each request's rows (its callbacks run here), then the count
+            with TraceAnnotation("serving.scatter"):
+                done = self._clock()
+                off = 0
+                n_ok = 0
+                lats = []
+                for r in batch:
+                    if self._resolve(r.future,
+                                     _slice_rows(y, off, off + r.rows)):
+                        n_ok += 1
+                        lats.append((done - r.t_submit, r.priority))
+                    off += r.rows
+                with self._cond:
+                    self._stats.n_batches += 1
+                    self._stats.rows_executed += rows
+                    self._stats.rows_padded += bucket - rows
+                    self._stats.batch_hist.add(rows)
+                    self._stats.n_completed += n_ok
+                    for lat, prio in lats:
+                        self._stats.record_latency(lat, prio)
+                    self._stats.worker_batches[worker] = \
+                        self._stats.worker_batches.get(worker, 0) + 1
+                    # the batch leaves flight in the same locked section
+                    # that counts it completed, so no snapshot can observe
+                    # requests both completed and in flight (the callers'
+                    # ``finally`` removal stays as an identity-checked
+                    # backstop for the watchdog-requeue path)
+                    if self._inflight.get(worker) is batch:
+                        del self._inflight[worker]
+                    self._cond.notify_all()
 
     def step(self) -> bool:
         """Expire deadlines and execute at most one ready batch *now*
@@ -1142,7 +1198,7 @@ class AsyncServer:
         if batch is None:
             return False
         try:
-            self._execute(batch, worker=0, seq=seq)
+            self._execute(batch, worker=0, seq=seq, formed=now)
         except WorkerCrashError:
             with self._cond:
                 self._stats.n_worker_crashes += 1
@@ -1177,12 +1233,13 @@ class AsyncServer:
                         self._batch_seq += 1
                         self._inflight[worker] = batch
                         break
-                    self._cond.wait(self._wait_timeout_locked(now))
+                    with TraceAnnotation("serving.idle", worker=worker):
+                        self._cond.wait(self._wait_timeout_locked(now))
             if self._monitor is not None:
                 self._monitor.beat(worker)
             t0 = self._clock()
             try:
-                self._execute(batch, worker, seq=seq)
+                self._execute(batch, worker, seq=seq, formed=now)
             except WorkerCrashError:
                 with self._cond:        # counted here, not when the
                     self._stats.n_worker_crashes += 1    # supervisor sees it
@@ -1393,6 +1450,7 @@ class AsyncServer:
                         for k, v in sorted(self._stats.latency_by_class
                                            .items())},
                 },
+                "gc": gc_pauses(),
             }
 
     # -- lifecycle -----------------------------------------------------------
@@ -1451,6 +1509,9 @@ class AsyncServer:
                 r = self._pending.popleft()
                 self._resolve(r.future, exc=ServerClosedError(
                     "server closed before execution"))
+            release, self._holds_gc_hook = self._holds_gc_hook, False
+        if release:
+            GC_PAUSES.release()
 
     @property
     def closed(self) -> bool:

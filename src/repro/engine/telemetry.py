@@ -23,16 +23,31 @@ All three are thread-safe (one internal lock each) and support
 under the server lock, so a snapshot is internally consistent and
 detached from the live counters.  ``state_size()`` reports the number
 of stored scalars — the long-run stress test asserts it stops growing.
+
+Collector pauses are counted by one process-wide hook on
+``gc.callbacks`` (:class:`GcPauses`, the instance :data:`GC_PAUSES`):
+each collection is a ``runtime.gc`` span (arg ``generation``) in the
+profiler's trace, on the thread that collected, and adds to three
+counters per generation — ``pauses``, ``pause_s``, ``pause_max_s`` —
+read by :func:`gc_pauses`.  ``AsyncServer`` holds the hook from its
+construction to its ``close()``.
 """
 from __future__ import annotations
 
+import gc
 import threading
+import time
 from typing import Dict, Iterable, List, Mapping, Optional, Sequence, Tuple
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "SizeHistogram",
     "P2Quantile",
     "StreamingQuantiles",
+    "GcPauses",
+    "GC_PAUSES",
+    "gc_pauses",
 ]
 
 
@@ -405,3 +420,78 @@ class StreamingQuantiles:
     def __repr__(self) -> str:
         return (f"StreamingQuantiles(count={self.count}, "
                 f"qs={self.qs}, exact={self.exact})")
+
+
+# ---------------------------------------------------------------------------
+# Garbage-collector pauses
+# ---------------------------------------------------------------------------
+
+class GcPauses:
+    """A ``gc.callbacks`` hook, shared by reference count: the first
+    :meth:`acquire` installs it, the last :meth:`release` removes it.
+
+    While installed, every collection opens a ``runtime.gc`` span
+    (``jax.profiler.TraceAnnotation``, arg ``generation``) on the thread
+    that collects and closes it when the collection ends, and adds the
+    pause to its generation's counters.  A pause is counted whole or not
+    at all: one already running when the hook goes in is skipped."""
+
+    def __init__(self) -> None:
+        self._users = 0
+        self._lock = threading.Lock()
+        # generation -> (pauses, pause_s, pause_max_s); each update
+        # replaces one item, so a reader never sees half of one
+        self._counts: Dict[int, Tuple[int, float, float]] = {}
+        self._open = threading.local()
+
+    def acquire(self) -> None:
+        with self._lock:
+            self._users += 1
+            if self._users == 1:
+                gc.callbacks.append(self._callback)
+
+    def release(self) -> None:
+        with self._lock:
+            if self._users == 0:
+                raise RuntimeError("GcPauses.release without acquire")
+            self._users -= 1
+            if self._users == 0:
+                gc.callbacks.remove(self._callback)
+
+    @property
+    def installed(self) -> bool:
+        return self._callback in gc.callbacks
+
+    def _callback(self, phase: str, info: Mapping) -> None:
+        if phase == "start":
+            span = TraceAnnotation("runtime.gc",
+                                   generation=info["generation"])
+            span.__enter__()
+            self._open.span = span
+            self._open.t0 = time.perf_counter()
+            return
+        span = getattr(self._open, "span", None)
+        if span is None:
+            return
+        pause = time.perf_counter() - self._open.t0
+        self._open.span = None
+        span.__exit__(None, None, None)
+        g = info["generation"]
+        n, total, longest = self._counts.get(g, (0, 0.0, 0.0))
+        self._counts[g] = (n + 1, total + pause, max(longest, pause))
+
+    def snapshot(self) -> Dict[int, Dict[str, float]]:
+        """``{generation: {"pauses", "pause_s", "pause_max_s"}}`` counted
+        so far in this process, for the generations that collected."""
+        counts = dict(self._counts)
+        return {g: {"pauses": n, "pause_s": total, "pause_max_s": longest}
+                for g, (n, total, longest) in sorted(counts.items())}
+
+
+GC_PAUSES = GcPauses()
+
+
+def gc_pauses() -> Dict[int, Dict[str, float]]:
+    """The process's collector-pause counters (see :class:`GcPauses`);
+    counted only while some ``AsyncServer`` is open."""
+    return GC_PAUSES.snapshot()

@@ -300,6 +300,62 @@ def test_sharded_equivalence_two_forced_devices():
     assert "SHARDED-OK" in proc.stdout
 
 
+_REPLICAS_SCRIPT = textwrap.dedent("""
+    import itertools
+    import numpy as np
+    import jax, jax.numpy as jnp
+    from repro.core.graph import Graph
+    from repro.engine import AsyncServer, DynamicBatchPolicy
+    from repro.engine import compile as compile_session
+
+    devs = jax.devices()
+    assert len(devs) == 4, devs
+    g = Graph()
+    g.add("in", "input")
+    g.add("c1", "conv2d", ["in"], in_channels=3, out_channels=16, kh=3,
+          kw=3, stride=2, pad=1)
+    g.add("gap", "global_avg_pool", ["c1"])
+    g.add("fl", "flatten", ["gap"])
+    g.add("fc", "dense", ["fl"], units=10)
+    g.mark_output("fc")
+    sess = compile_session(g, {"in": (4, 3, 16, 16)})
+    m = sess.specialize(4)
+    x = jnp.asarray(np.random.default_rng(0).normal(size=(3, 3, 16, 16))
+                    .astype(np.float32))
+    ys = []
+    # one pumped server a device, each with worker 0 on that device: the
+    # first batch compiles the bucket on all four devices at once, and
+    # the later servers compile no forward of their own
+    for w in range(4):
+        clock = itertools.count()
+        srv = AsyncServer(sess, DynamicBatchPolicy(max_batch=4,
+                                                   max_wait_ms=1.0),
+                          workers=4, devices=devs[w:] + devs[:w],
+                          autostart=False, clock=lambda: float(next(clock)))
+        f = srv.submit(x)
+        assert srv.step()
+        ys.append(f.result(timeout=0))
+        srv.close()
+        assert m._forward._cache_size() == 4, (w, m._forward._cache_size())
+    assert [next(iter(y.devices())) for y in ys] == devs
+    assert all(np.asarray(y).tobytes() == np.asarray(ys[0]).tobytes()
+               for y in ys)
+    print("REPLICAS-OK")
+""")
+
+
+def test_first_batch_compiles_every_workers_replica_four_devices():
+    env = dict(os.environ)
+    env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
+    env["XLA_FLAGS"] = merge_xla_flag(env.get("XLA_FLAGS", ""),
+                                      DEVICE_COUNT_FLAG, 4)
+    proc = subprocess.run([sys.executable, "-c", _REPLICAS_SCRIPT],
+                          env=env, capture_output=True, text=True,
+                          timeout=600)
+    assert proc.returncode == 0, proc.stdout + proc.stderr
+    assert "REPLICAS-OK" in proc.stdout
+
+
 # ---------------------------------------------------------------------------
 # Importing launch entry points must not configure devices (regression:
 # launch.dryrun used to call configure_cpu_devices(512) at import time,
