@@ -173,6 +173,8 @@ def conv_schedule_cost(wl: ConvWorkload, s: ConvSchedule,
     cin = wl.in_channels // wl.groups
     khkw = wl.kh * wl.kw
     variant = s.resolved_variant()
+    if variant == "xla_conv":
+        return _xla_conv_cost(wl, s, dtype_peak)
     if variant in ("tap_stack", "patch_gemm"):
         # one contraction over the stacked kh*kw*ic reduction
         util = mxu_utilization(
@@ -241,6 +243,35 @@ def conv_schedule_cost(wl: ConvWorkload, s: ConvSchedule,
     if _working_set_bytes(wl, s) > SPILL_BYTES:
         memory_s *= 8.0
     return CostBreakdown(compute_s=compute_s, memory_s=memory_s)
+
+
+def _xla_conv_cost(wl: ConvWorkload, s: ConvSchedule,
+                   dtype_peak: float) -> CostBreakdown:
+    """The compiler's own conv (variant ``xla_conv``): tiled by the
+    compiler, it contracts one tap's input channels at a time over every
+    output channel; input, weight and output cross HBM once, and no tap
+    tensor exists.  Splitting the output into ``oc_bn`` chunks is the
+    transpose every variant pays, fused into the epilogue."""
+    oh, ow = wl.out_hw
+    cin = wl.in_channels // wl.groups
+    b = wl.dtype_bytes
+    util = mxu_utilization(wl.batch * oh * ow, cin, wl.out_channels)
+    compute_s = wl.flops / (dtype_peak * max(util, 1e-3))
+    poh, pow_ = wl.pooled_out_hw
+    input_bytes = wl.batch * cin * wl.height * wl.width * b
+    weight_bytes = wl.out_channels * cin * wl.kh * wl.kw * b
+    output_bytes = wl.batch * wl.out_channels * poh * pow_ * b
+    # the NCHW (ic_bn 1) and NHWC (ic_bn = cin) views are free; any other
+    # blocking merges the input's chunks in one more pass
+    merge_bytes = 2 * input_bytes if 1 < s.ic_bn < cin else 0
+    epi_bytes = epilogue_bytes(
+        (wl.batch, wl.out_channels, oh, ow), bn=wl.fused_bn,
+        relu=wl.fused_relu, residual=wl.fused_residual, fused=True,
+        dtype_bytes=b)
+    return CostBreakdown(
+        compute_s=compute_s,
+        memory_s=(input_bytes + weight_bytes + output_bytes + merge_bytes
+                  + epi_bytes) / HBM_BW)
 
 
 # ---------------------------------------------------------------------------

@@ -117,10 +117,18 @@ class ConvWorkload:
 #   patch_gemm — strided patch panels flattened to a single plain 2-D GEMM
 #                over the full kh*kw*ic reduction (the im2col lowering of
 #                Caffe con Troll, 1504.04343).
+#   xla_conv   — the compiler's own convolution on views of the blocked
+#                tensors (NCHW or NHWC input, HWIO weight); no tap tensor
+#                exists.  The only lowering of a lane-sparse input (fewer
+#                than XLA_CONV_BELOW channels, the RGB stems), whose every
+#                tap copy pads its 3-channel minor dim to 128 TPU lanes;
+#                never enumerated for wider convs.
 #
 # "auto" defers the choice to the kernel's static heuristic (PR-1 behavior:
 # tap_stack below sublane ic_bn, per_tap otherwise).
-VARIANTS = ("per_tap", "tap_stack", "scan", "patch_gemm")
+_TAP_VARIANTS = ("per_tap", "tap_stack", "scan", "patch_gemm")
+VARIANTS = _TAP_VARIANTS + ("xla_conv",)
+XLA_CONV_BELOW = 8    # input channels: one TPU sublane
 
 # Numeric-precision axis of the schedule space.  "int8" is weight-only
 # quantization (W8: per-output-channel symmetric int8 weights bound at
@@ -206,6 +214,12 @@ def candidate_schedules(wl: ConvWorkload, max_candidates: int = 0,
     row and it issues one GEMM per tap, so ``ow_bn`` is pinned to OW and
     the variant to ``per_tap`` — the jnp lowering axes do not exist there.
 
+    On the jnp path a conv with fewer than ``XLA_CONV_BELOW`` input
+    channels enumerates ``xla_conv`` alone, and only its (ic_bn, oc_bn)
+    pairs: the compiler's conv tiles the plane itself, so ``ow_bn`` and
+    ``oh_bn`` are pinned to the whole output plane and nothing is
+    unrolled.  Wider convs enumerate the blocked variants only.
+
     ``max_candidates`` > 0 truncates the (ic-major) enumeration — only
     useful for tests; the full space is bounded (≤ 6*6*4*2*2*4 tuples) and
     a truncated one never reaches past the first couple of ic_bn
@@ -223,9 +237,6 @@ def candidate_schedules(wl: ConvWorkload, max_candidates: int = 0,
         ocs = [f for f in ocs
                if wl.concat_offset % f == 0 and wl.concat_total % f == 0]
     ows = [f for f in _OW_CANDIDATES if ow % f == 0] or [1]
-    variants = VARIANTS
-    if pallas:
-        ows, variants = [ow], ("per_tap",)
     if wl.fused_pool:
         # fused pooling reduces over the whole conv plane before the store,
         # so the output blocking collapses to whole-plane rows — the pooled
@@ -234,10 +245,15 @@ def candidate_schedules(wl: ConvWorkload, max_candidates: int = 0,
         ohs = [oh]
     else:
         ohs = [f for f in (8, 4, 2, 1) if oh % f == 0] or [1]
+    variants, unrolls = _TAP_VARIANTS, (True, False)
+    if pallas:
+        ows, variants = [ow], ("per_tap",)
+    elif cin < XLA_CONV_BELOW:
+        ows, ohs, unrolls, variants = [ow], [oh], (False,), ("xla_conv",)
     out: List[ConvSchedule] = []
     for ic_bn, oc_bn, ow_bn in itertools.product(ics[:6], ocs[:6], ows[:4]):
         for oh_bn in ohs[:2]:
-            for unroll in (True, False):
+            for unroll in unrolls:
                 for variant in variants:
                     out.append(ConvSchedule(ic_bn, oc_bn, ow_bn, oh_bn,
                                             unroll, variant))

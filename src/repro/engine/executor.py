@@ -12,7 +12,7 @@ jitted with the (pre-transformed) params as a traced argument, so weight
 updates don't recompile.
 
 Each conv node executes under its planned ``ConvSchedule`` — including the
-lowering ``variant`` (per_tap / tap_stack / scan / patch_gemm, PR 2) the
+lowering ``variant`` (per_tap / tap_stack / scan / patch_gemm / xla_conv) the
 search picked for its workload; the schedule rides into
 ``kernels.ops.conv2d_blocked`` / ``conv2d_block_blocked`` which dispatch
 the jnp template accordingly (the Pallas path has a single VMEM-resident

@@ -37,7 +37,7 @@ def pad_blocked(x_blocked: jnp.ndarray, pad) -> jnp.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# Template variants: four lowerings of the same blocked direct conv
+# Template variants: lowerings of the same blocked direct conv
 # (ConvSchedule.variant — see core/schedule.py).  Each accumulator function
 # maps padded-input + blocked-weight to the fp32 accumulator in the
 # dot-natural (n, oh, ow, ko, oc) order — the einsum's M dims (n, h, w) stay
@@ -153,6 +153,36 @@ _ACC_FNS = {"per_tap": _acc_per_tap, "tap_stack": _acc_tap_stack,
             "scan": _acc_scan, "patch_gemm": _acc_patch_gemm}
 
 
+def _acc_xla_conv(x_blocked, w_blocked, stride, pad):
+    """The compiler's own convolution, padding passed to the conv, on a
+    view of the unpadded blocked input: NCHW when ``ic_bn`` is 1, NHWC
+    otherwise (exact for one channel chunk; several are merged), and an
+    HWIO view of the KCRS[x]c[y]k weight.  No tap tensor is materialized:
+    for a lane-sparse input (the RGB stem's 3 channels pad to 128 TPU
+    lanes) every strided tap copy of the other variants moves ~40x its
+    real bytes.  Its operations carry the ``xla_conv`` scope in their
+    ``op_name``."""
+    n, ci, h, w, ic_bn = x_blocked.shape
+    ko, ci_w, kh, kw, ic_w, oc_bn = w_blocked.shape
+    ph, pw = _pad_hw(pad)
+    with jax.named_scope("xla_conv"):
+        if ic_bn == 1:
+            x, lhs = x_blocked.reshape(n, ci, h, w), "NCHW"
+        else:
+            x = x_blocked.transpose(0, 2, 3, 1, 4).reshape(
+                n, h, w, ci * ic_bn)
+            lhs = "NHWC"
+        wt = w_blocked.transpose(2, 3, 1, 4, 0, 5).reshape(
+            kh, kw, ci_w * ic_w, ko * oc_bn)
+        out = jax.lax.conv_general_dilated(
+            x.astype(jnp.float32), wt.astype(jnp.float32),
+            (stride, stride), ((ph, ph), (pw, pw)),
+            dimension_numbers=(lhs, "HWIO", "NHWC"),
+            preferred_element_type=jnp.float32)
+        oh, ow = out.shape[1:3]
+        return out.reshape(n, oh, ow, ko, oc_bn)
+
+
 # ---------------------------------------------------------------------------
 # int8 instantiations (ConvSchedule.dtype == "int8", weight-only W8).
 #
@@ -194,7 +224,7 @@ _ACC_FNS_INT8 = {"tap_stack": _acc_tap_stack_int8,
 def apply_epilogue_fp32(acc: jnp.ndarray, scale, shift, residual,
                         spec: EpilogueSpec) -> jnp.ndarray:
     """The composable epilogue on the blocked fp32 accumulator
-    ``(n, Ko, oh, ow, oc_bn)`` — shared by all four template variants, so a
+    ``(n, Ko, oh, ow, oc_bn)`` — shared by every template variant, so a
     new epilogue stage is written once and every lowering gets it.  Order is
     fixed (see ``core.epilogue``): affine -> residual -> ReLU -> pool."""
     if scale is not None:   # (Ko, oc_bn) per-channel affine
@@ -237,18 +267,11 @@ def _conv2d_block_core(x_blocked, w_blocked, scale, shift, residual, out_buf,
     scale through ``scale`` — the shared epilogue applies it like a BN
     scale.
     """
-    xp = pad_blocked(x_blocked, pad)
-    n, ci, hp, wp, ic_bn = xp.shape
+    if variant in ("auto", None):
+        variant = "tap_stack" if x_blocked.shape[-1] < 8 else "per_tap"
     if w_prelaid:
         assert variant == "patch_gemm", \
             f"pre-laid panel weight requires patch_gemm, got {variant!r}"
-        ci_w, kh, kw, ic_w, ko, oc_bn = w_blocked.shape
-    else:
-        ko, ci_w, kh, kw, ic_w, oc_bn = w_blocked.shape
-    oh = (hp - kh) // stride + 1
-    ow = (wp - kw) // stride + 1
-    if variant in ("auto", None):
-        variant = "tap_stack" if ic_bn < 8 else "per_tap"
     if dtype == "int8":
         if variant not in _ACC_FNS_INT8:
             raise ValueError(
@@ -260,13 +283,19 @@ def _conv2d_block_core(x_blocked, w_blocked, scale, shift, residual, out_buf,
                 "in the epilogue's scale operand")
         if w_prelaid:
             _require_int8_weight(w_blocked, variant)
+    oc_bn = w_blocked.shape[-1]
+    if variant == "xla_conv":
+        acc = _acc_xla_conv(x_blocked, w_blocked, stride, pad)
+    else:
+        xp = pad_blocked(x_blocked, pad)
+        kh, kw = w_blocked.shape[1:3] if w_prelaid else w_blocked.shape[2:4]
+        oh = (xp.shape[2] - kh) // stride + 1
+        ow = (xp.shape[3] - kw) // stride + 1
+        if w_prelaid:
             acc = _patch_gemm(xp, w_blocked, stride, oh, ow)
         else:
-            acc = _ACC_FNS_INT8[variant](xp, w_blocked, stride, oh, ow)
-    elif w_prelaid:
-        acc = _patch_gemm(xp, w_blocked, stride, oh, ow)
-    else:
-        acc = _ACC_FNS[variant](xp, w_blocked, stride, oh, ow)
+            acc_fns = _ACC_FNS_INT8 if dtype == "int8" else _ACC_FNS
+            acc = acc_fns[variant](xp, w_blocked, stride, oh, ow)
     acc = acc.transpose(0, 3, 1, 2, 4)               # -> (n, ko, oh, ow, oc)
     acc = apply_epilogue_fp32(acc, scale, shift, residual, spec)
     out = acc.astype(x_blocked.dtype)

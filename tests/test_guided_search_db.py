@@ -13,7 +13,8 @@ import pytest
 from repro.core import local_search as ls
 from repro.core.local_search import (ScheduleDatabase, _wl_key,
                                      guided_local_search)
-from repro.core.schedule import VARIANTS, ConvWorkload
+from repro.core.schedule import (VARIANTS, ConvWorkload,
+                                 candidate_schedules)
 
 WL = ConvWorkload(batch=1, in_channels=64, out_channels=64, height=28,
                   width=28, kh=3, kw=3, stride=1, pad=1)
@@ -42,11 +43,15 @@ def test_guided_search_deterministic_stub(monkeypatch):
 
     assert res.measured is True
     assert res.search_budget == (4, 2)
-    # every variant was shortlisted and measured at least per_variant times
-    # (dedup by (ic_bn, oc_bn, variant) can only add distinct entries)
+    # every variant of the workload's space (the four tap loop nests: a
+    # 64-channel conv never enumerates xla_conv) was shortlisted and
+    # measured at least per_variant times (dedup by (ic_bn, oc_bn, variant)
+    # can only add distinct entries)
+    space = {s.resolved_variant() for s in candidate_schedules(WL)}
+    assert space == set(VARIANTS) - {"xla_conv"}
     by_variant = {v: [s for s in measured if s.resolved_variant() == v]
-                  for v in VARIANTS}
-    for v in VARIANTS:
+                  for v in space}
+    for v in space:
         assert len(by_variant[v]) >= 2, f"variant {v} not shortlisted"
     # no duplicate measurements: the shortlist dedups identical computations
     keys = [(s.ic_bn, s.oc_bn, s.resolved_variant()) for s in measured]

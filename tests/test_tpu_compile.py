@@ -16,6 +16,7 @@ from repro.core.epilogue import EpilogueSpec, PoolSpec
 from repro.core.schedule import ConvSchedule
 from repro.kernels.conv2d_nchwc import conv2d_nchwc_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
+from repro.kernels.ops import conv2d_block_jnp
 from repro.kernels.matmul_blocked import MatmulSchedule, matmul_padded
 
 
@@ -94,6 +95,27 @@ def test_conv2d_nchwc_pallas_compiles(shape_of, case):
     assert "tpu_custom_call" in compiled.as_text()
     out_hw = pool.out_hw(oh, oh) if pool is not None else (oh, oh)
     assert compiled.out_info.shape == (n, cout // oc) + out_hw + (oc,)
+
+
+def test_stem_xla_conv_bytes_accessed(shape_of):
+    """The ResNet-50 stem at batch 8 and 224 on the jnp path, as the
+    planner lowers it (``xla_conv``, ic_bn 1, oc_bn 64), with shift, ReLU
+    and the fused 3x3/2 max-pool at "highest": XLA's cost analysis reads
+    under 2 GB accessed.  The tap_stack lowering, whose 49 tap copies pad
+    3 channels to 128 lanes, reads 8.2 GB."""
+    spec = EpilogueSpec(relu=True, pool=PoolSpec("max", 3, 2, 1))
+
+    def stem(x, w, shift):
+        return conv2d_block_jnp(x, w, None, shift, stride=2, pad=3,
+                                epilogue=spec, variant="xla_conv")
+
+    with jax.default_matmul_precision("highest"):
+        compiled = _compile(stem, shape_of((8, 3, 224, 224, 1)),
+                            shape_of((1, 3, 7, 7, 1, 64)), shape_of((1, 64)))
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    assert compiled.out_info.shape == (8, 1, 56, 56, 64)
+    assert cost["bytes accessed"] < 2e9
 
 
 def test_matmul_padded_compiles(shape_of):
