@@ -157,8 +157,13 @@ class CheckpointStore:
         leaves = {}
         for path, rec in manifest["leaves"].items():
             try:
-                leaves[path] = np.load(d / rec["file"])
-            except (ValueError, OSError, EOFError) as e:
+                arr = np.load(d / rec["file"])
+                if arr.dtype.kind == "V":
+                    # NumPy writes extension dtypes (bfloat16) as raw
+                    # bytes; the manifest records the dtype to view them as
+                    arr = arr.view(jax.numpy.dtype(rec["dtype"]))
+                leaves[path] = arr
+            except (ValueError, OSError, EOFError, TypeError) as e:
                 # np.load on a truncated/garbled .npy raises a bare
                 # ValueError ("Cannot load file...") — re-raise with the
                 # blob named so artifact loaders can wrap it typed

@@ -74,12 +74,15 @@ ARCHS: Dict[str, LMConfig] = {
         n_heads=32, n_kv=32, d_ff=6912, vocab=50304, norm="layernorm",
         dtype="bfloat16"),
 
-    # [dense] GQA, RoPE, plain-GELU MLP
+    # [dense] GQA 12:1, RoPE, plain-GELU MLP, LayerNorm, biases on every
+    # projection, a 4096-position sliding window; values from
+    # bigcode/starcoder2-3b's config.json (tied embeddings assumed)
     "starcoder2-3b": LMConfig(
         name="starcoder2-3b", family="dense", n_layers=30, d_model=3072,
         n_heads=24, n_kv=2, head_dim=128, d_ff=12288, vocab=49152,
-        norm="layernorm", mlp_gated=False, qkv_bias=True,
-        rope_theta=1e5, dtype="bfloat16"),
+        norm="layernorm", norm_eps=1e-5, mlp_gated=False, qkv_bias=True,
+        proj_bias=True, rope_theta=999999.4420358813, local_window=4096,
+        tie_embeddings=True, dtype="bfloat16"),
 
     # [dense] llama-arch GQA
     "yi-9b": LMConfig(
@@ -100,8 +103,10 @@ def reduced(cfg: LMConfig) -> LMConfig:
         n_kv=min(cfg.n_kv, 4) if cfg.n_kv else 0,
         head_dim=16 if cfg.n_heads else 0,
         d_ff=128 if cfg.d_ff else 0, vocab=512,
-        qkv_bias=cfg.qkv_bias, mlp_gated=cfg.mlp_gated, norm=cfg.norm,
+        qkv_bias=cfg.qkv_bias, proj_bias=cfg.proj_bias,
+        mlp_gated=cfg.mlp_gated, norm=cfg.norm, norm_eps=cfg.norm_eps,
         rope_theta=cfg.rope_theta, tie_embeddings=cfg.tie_embeddings,
+        local_window=8 if cfg.local_window else 0,
         dtype="float32", remat=False)
     if cfg.family == "moe":
         kw.update(n_experts=8, top_k=min(cfg.top_k, 2), moe_d_ff=96,

@@ -13,7 +13,15 @@ layers) and windowed KV rings, so a prompt prefills the largest bucket
 program, one step each.
 
 ``generate`` is greedy (argmax) decode with an optional ``on_token``
-callback — the hook ``AsyncServer.submit_stream`` streams tokens through.
+callback — the hook ``AsyncServer.submit_stream`` streams tokens through —
+and, with ``keep_logits``, each step's logits left on the device.  Each
+phase is a ``jax.profiler`` span on the host (``lm.prefill`` with its
+``bucket`` and ``prompt_len``, ``lm.catchup`` and ``lm.decode`` with their
+``steps``), and the two jitted programs put every device op under the
+``jax.named_scope`` of its phase (``lm.prefill``, ``lm.decode``), so a
+profiler trace attributes device time to phases.  ``counters()`` counts
+the work done (``COUNTERS``) for readers that take a delta over a
+window.
 Streaming is observational: the callback sees exactly the tokens the
 returned array holds, so streamed and unstreamed decode are bit-identical
 by construction, and the serving layer's watchdog may re-execute a
@@ -38,6 +46,7 @@ from typing import Callable, Dict, Optional, Sequence, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.checkpoint.store import CheckpointStore, dir_checksums, sha256_file
 from repro.engine.telemetry import SizeHistogram
@@ -47,6 +56,12 @@ from repro.models.lm import (LMConfig, decode_step, init_cache, init_params,
                              prefill)
 
 __all__ = ["LMSession", "compile_lm"]
+
+# requests generated; prompt tokens in them; tokens prefilled (the
+# buckets); decode steps that caught a prompt's tail up, and all decode
+# steps (catch-up included); tokens generated
+COUNTERS = ("requests", "prompt_tokens", "prefill_tokens", "catchup_steps",
+            "decode_steps", "generated_tokens")
 
 
 def _lm_archs() -> Dict[str, LMConfig]:
@@ -82,6 +97,13 @@ class LMSession:
         self._lock = threading.RLock()
         self._prefill_progs: Dict[int, Callable] = {}
         self._decode_prog: Optional[Callable] = None
+        self._counts = dict.fromkeys(COUNTERS, 0)
+        self._counts_lock = threading.Lock()
+
+    @property
+    def params(self):
+        """The bound parameters, as the programs take them."""
+        return self._params
 
     # -- the surface AsyncServer speaks --------------------------------------
     @property
@@ -105,10 +127,11 @@ class LMSession:
             if fn is None:
                 cfg, max_len = self.cfg, self.max_len
 
-                def run(params, toks):
-                    return prefill(params, cfg, toks, max_len=max_len)
+                def lm_prefill(params, toks):
+                    with jax.named_scope("lm.prefill"):
+                        return prefill(params, cfg, toks, max_len=max_len)
 
-                fn = jax.jit(run)
+                fn = jax.jit(lm_prefill)
                 self._prefill_progs[bucket] = fn
         return fn
 
@@ -117,11 +140,17 @@ class LMSession:
             if self._decode_prog is None:
                 cfg = self.cfg
 
-                def run(params, token, cache, pos):
-                    return decode_step(params, cfg, token, cache, pos)
+                def lm_decode(params, token, cache, pos):
+                    with jax.named_scope("lm.decode"):
+                        return decode_step(params, cfg, token, cache, pos)
 
-                self._decode_prog = jax.jit(run)
+                self._decode_prog = jax.jit(lm_decode)
         return self._decode_prog
+
+    def counters(self) -> Dict[str, int]:
+        """The work done since construction, by ``COUNTERS`` name."""
+        with self._counts_lock:
+            return dict(self._counts)
 
     def bucket_for(self, prompt_len: int) -> Optional[int]:
         """Largest seq bucket ``<=`` the prompt length, or None (the
@@ -144,23 +173,28 @@ class LMSession:
 
     # -- generation ------------------------------------------------------------
     def generate(self, tokens, max_new_tokens: int, *,
-                 on_token: Optional[Callable[[int, np.ndarray], None]] = None
-                 ) -> np.ndarray:
+                 on_token: Optional[Callable[[int, np.ndarray], None]] = None,
+                 keep_logits: bool = False):
         """Greedy decode: returns the ``(batch, max_new_tokens)`` int32
         token array.  ``on_token(step, tokens_b)`` fires as each step's
         tokens become available — the streaming hook; it observes the
         exact values the return array holds (bit-identical by
         construction) and duplicate replays of already-emitted steps are
         the *caller's* concern (``TokenStream`` dedups by step index, so
-        a watchdog-requeued generation is idempotent)."""
-        toks = jnp.asarray(tokens)
+        a watchdog-requeued generation is idempotent).  With
+        ``keep_logits`` it returns ``(tokens, logits)`` instead, where
+        ``logits[t]`` is the ``(batch, vocab)`` device array the step-``t``
+        tokens were taken from; keeping them moves nothing to the host."""
+        # the prompt stays on the host: a slice of it per prefill bucket or
+        # catch-up position would otherwise compile a program per position
+        toks = np.asarray(tokens)
         if toks.ndim != 2 or toks.shape[0] != self.batch:
             raise ValueError(
                 f"tokens must be ({self.batch}, prompt_len), got "
                 f"{tuple(toks.shape)}")
-        if not jnp.issubdtype(toks.dtype, jnp.integer):
+        if not np.issubdtype(toks.dtype, np.integer):
             raise ValueError(f"tokens must be integers, got {toks.dtype}")
-        toks = toks.astype(jnp.int32)
+        toks = toks.astype(np.int32)
         prompt_len = int(toks.shape[1])
         if prompt_len < 1:
             raise ValueError("empty prompt")
@@ -176,29 +210,43 @@ class LMSession:
         # re-executes, and demand must count once per request
         dec = self._decode()
         bucket = self.bucket_for(prompt_len)
-        if bucket is None:
-            # below every bucket: run the whole prompt through decode
-            cache = init_cache(self.cfg, self.batch, self.max_len)
-            logits = None
-            start = 0
-        else:
-            cache, logits = self._prefill_for(bucket)(
-                self._params, toks[:, :bucket])
-            start = bucket
-        for p in range(start, prompt_len):       # decode catch-up
-            logits, cache = dec(self._params, toks[:, p:p + 1], cache,
-                                jnp.int32(p))
-        out = []
-        for t in range(max_new_tokens):
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # (batch,)
-            step = np.asarray(nxt)
-            out.append(step)
-            if on_token is not None:
-                on_token(t, step)
-            if t + 1 < max_new_tokens:           # advance for the next token
-                logits, cache = dec(self._params, nxt[:, None], cache,
-                                    jnp.int32(prompt_len + t))
-        return np.stack(out, axis=1)
+        start = bucket or 0
+        with TraceAnnotation("lm.prefill", bucket=start,
+                             prompt_len=prompt_len):
+            if bucket is None:
+                # below every bucket: run the whole prompt through decode
+                cache = init_cache(self.cfg, self.batch, self.max_len)
+                logits = None
+            else:
+                cache, logits = self._prefill_for(bucket)(
+                    self._params, toks[:, :bucket])
+        with TraceAnnotation("lm.catchup", steps=prompt_len - start):
+            for p in range(start, prompt_len):
+                logits, cache = dec(self._params, toks[:, p:p + 1], cache,
+                                    jnp.int32(p))
+        out, kept = [], []
+        with TraceAnnotation("lm.decode", steps=max_new_tokens - 1):
+            for t in range(max_new_tokens):
+                if keep_logits:
+                    kept.append(logits)
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                step = np.asarray(nxt)
+                out.append(step)
+                if on_token is not None:
+                    on_token(t, step)
+                if t + 1 < max_new_tokens:       # advance for the next token
+                    logits, cache = dec(self._params, nxt[:, None], cache,
+                                        jnp.int32(prompt_len + t))
+        done = {"requests": 1, "prompt_tokens": prompt_len,
+                "prefill_tokens": start,
+                "catchup_steps": prompt_len - start,
+                "decode_steps": prompt_len - start + max_new_tokens - 1,
+                "generated_tokens": max_new_tokens}
+        with self._counts_lock:
+            for k, v in done.items():
+                self._counts[k] += v
+        tokens_out = np.stack(out, axis=1)
+        return (tokens_out, kept) if keep_logits else tokens_out
 
     # -- persistence -----------------------------------------------------------
     def save(self, path: Union[str, Path]) -> Path:
@@ -295,7 +343,9 @@ class LMSession:
         cfg_d = dict(lm["config"])
         cfg_d["block_pattern"] = tuple(cfg_d.get("block_pattern") or ())
         cfg = LMConfig(**cfg_d)
-        template = init_params(cfg, jax.random.PRNGKey(0))
+        # the tree's structure only: no parameters are drawn
+        template = jax.eval_shape(lambda k: init_params(cfg, k),
+                                  jax.random.PRNGKey(0))
         try:
             params, _, _ = CheckpointStore(path / "weights").restore(
                 template, step=0)
@@ -303,6 +353,7 @@ class LMSession:
             raise ArtifactCorruptError(
                 f"artifact weights under {path}/weights are corrupt or "
                 f"incomplete: {e}") from e
+        params = jax.device_put(params)   # once, not on every call
         sess = cls(cfg, params, max_len=int(lm["max_len"]),
                    batch=int(lm["batch"]),
                    seq_buckets=[int(b) for b in lm.get("seq_buckets", [])],

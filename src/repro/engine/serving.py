@@ -112,8 +112,9 @@ it), ``serving.device_wait`` (``block_until_ready``) and
 ``serving.scatter`` (slice each request's rows, resolve futures — the
 clients' callbacks run here — and count the batch); the slice that drops
 the padded rows lies between the last two.  A streamed generation gets
-``serving.batch`` alone.  The server also holds the collector-pause hook
-of ``engine/telemetry.py`` (``runtime.gc`` spans, ``health()["gc"]``).
+``serving.batch`` with the LM session's ``lm.*`` spans inside it.  The
+server also holds the collector-pause hook of ``engine/telemetry.py``
+(``runtime.gc`` spans, ``health()["gc"]``).
 
 Tests drive the scheduling deterministically: construct with
 ``autostart=False`` and a fake ``clock``, then pump :meth:`AsyncServer.step`
@@ -352,6 +353,7 @@ class StreamRequest(Request):
     max_new_tokens: int = dataclasses.field(kw_only=True, default=1)
     stream: Optional[TokenStream] = dataclasses.field(kw_only=True,
                                                       default=None)
+    keep_logits: bool = dataclasses.field(kw_only=True, default=False)
 
 
 class BatchPolicy:
@@ -835,7 +837,8 @@ class AsyncServer:
 
     def submit_stream(self, tokens, max_new_tokens: int,
                       deadline_ms: Optional[float] = None,
-                      priority: Optional[str] = None) -> TokenStream:
+                      priority: Optional[str] = None,
+                      keep_logits: bool = False) -> TokenStream:
         """Enqueue one streamed LM generation; returns a
         :class:`TokenStream` yielding each decode step's tokens as the
         worker produces them (``StopIteration`` when the generation
@@ -847,7 +850,9 @@ class AsyncServer:
         already streaming), overload shedding, retry/requeue, and worker
         supervision apply unchanged, and a watchdog-requeued generation
         replays idempotently (greedy decode is deterministic, and the
-        stream dedups re-emitted steps).  Requires a session with a
+        stream dedups re-emitted steps).  With ``keep_logits`` the
+        future resolves with ``generate``'s ``(tokens, logits)``: each
+        step's logits, left on the device.  Requires a session with a
         ``generate`` method (:class:`~repro.engine.lm_session.LMSession`)."""
         if not hasattr(self.session, "generate"):
             raise ServingError(
@@ -907,7 +912,8 @@ class AsyncServer:
                     self._stats.n_shed += 1
             self._pending.append(StreamRequest(
                 x, rows, fut, now, deadline, priority=priority, rank=rank,
-                max_new_tokens=int(max_new_tokens), stream=stream))
+                max_new_tokens=int(max_new_tokens), stream=stream,
+                keep_logits=bool(keep_logits)))
             self._stats.n_submitted += 1
             self._stats.arrival_hist.add(rows)
             self._stats.queue_depth_peak = max(
@@ -1133,7 +1139,8 @@ class AsyncServer:
                     # future
                     r = batch[0]
                     y = self.session.generate(r.x, r.max_new_tokens,
-                                              on_token=r.stream.push)
+                                              on_token=r.stream.push,
+                                              keep_logits=r.keep_logits)
                 else:
                     with TraceAnnotation("serving.gather"):
                         xs = batch[0].x if len(batch) == 1 else \
@@ -1157,7 +1164,8 @@ class AsyncServer:
                 n_ok = 0
                 lats = []
                 for r in batch:
-                    if self._resolve(r.future,
+                    # a stream runs alone: its whole result is its own
+                    if self._resolve(r.future, y if stream else
                                      _slice_rows(y, off, off + r.rows)):
                         n_ok += 1
                         lats.append((done - r.t_submit, r.priority))
@@ -1451,6 +1459,9 @@ class AsyncServer:
                                            .items())},
                 },
                 "gc": gc_pauses(),
+                # an LM session's own work counters (LMSession.COUNTERS)
+                "lm": (self.session.counters()
+                       if hasattr(self.session, "counters") else None),
             }
 
     # -- lifecycle -----------------------------------------------------------

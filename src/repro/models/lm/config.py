@@ -33,6 +33,8 @@ class LMConfig:
     vocab: int
     head_dim: int = 0          # 0 -> d_model // n_heads
     qkv_bias: bool = False
+    proj_bias: bool = False    # biases on the attention output and the MLP
+                               # projections too (starcoder2's use_bias)
     mlp_gated: bool = True     # SwiGLU (llama-like) vs plain GELU MLP
     norm: str = "rmsnorm"      # "rmsnorm" | "layernorm"
     rope_theta: float = 10000.0
@@ -65,7 +67,7 @@ class LMConfig:
 
     # hybrid (recurrentgemma / griffin) --------------------------------------
     block_pattern: Tuple[str, ...] = ()   # cycled over layers, e.g. (rec, rec, attn)
-    local_window: int = 0
+    local_window: int = 0      # hybrid attn layers; dense: sliding window
     lru_width: int = 0
 
     # encoder-decoder (whisper) ----------------------------------------------
@@ -102,11 +104,18 @@ class LMConfig:
         n = v * d                                   # embedding
         if not self.tie_embeddings:
             n += v * d                              # lm head
+        norm = d * (2 if self.norm == "layernorm" else 1)
+        n += norm                                   # final norm
         attn = d * h * hd + 2 * d * kv * hd + h * hd * d
         mlp = (3 if self.mlp_gated else 2) * d * ff
+        if self.qkv_bias:
+            attn += h * hd + 2 * kv * hd
+        if self.proj_bias:
+            attn += d
+            mlp += ff + d
         per_layer = 0
         if self.family in ("dense", "moe", "vlm", "encdec"):
-            per_layer = attn
+            per_layer = attn + 2 * norm
             if self.family == "moe":
                 expert = (3 if self.mlp_gated else 2) * d * self.moe_d_ff
                 per_layer += self.n_experts * expert + d * self.n_experts

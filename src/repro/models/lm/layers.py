@@ -139,16 +139,24 @@ def flash_attention_xla(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
 
 
 def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
-                     v_cache: jnp.ndarray, cache_len) -> jnp.ndarray:
+                     v_cache: jnp.ndarray, cache_len,
+                     window: int = 0) -> jnp.ndarray:
     """Single-position attention against a (B, Hkv, S_max, D) cache.
-    ``cache_len`` masks positions >= the currently valid length."""
+    ``cache_len`` masks positions >= the currently valid length; a
+    ``window`` > 0 also masks those < ``cache_len - window``, so the
+    query at position ``p = cache_len - 1`` sees ``p - window < k <= p``
+    (the rule ``flash_attention_xla`` applies in prefill)."""
     b, hq, one, d = q.shape
     hkv, smax = k_cache.shape[1], k_cache.shape[2]
     g = hq // hkv
     qg = q.reshape(b, hkv, g, d)
     logits = jnp.einsum("bhgd,bhkd->bhgk", qg.astype(jnp.float32),
                         k_cache.astype(jnp.float32)) / math.sqrt(d)
-    valid = jnp.arange(smax)[None] < jnp.asarray(cache_len).reshape(-1, 1)
+    k_pos = jnp.arange(smax)[None]
+    cache_len = jnp.asarray(cache_len).reshape(-1, 1)
+    valid = k_pos < cache_len
+    if window > 0:
+        valid = valid & (k_pos >= cache_len - window)
     logits = jnp.where(valid[:, None, None], logits, NEG_INF)
     p = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhgk,bhkd->bhgd", p, v_cache.astype(jnp.float32))
@@ -211,8 +219,10 @@ def attention(x: jnp.ndarray, p: Dict, cfg: LMConfig, *,
                                   q_chunk=cfg.attn_q_chunk,
                                   kv_chunk=cfg.attn_kv_chunk)
         new_kv = (kt, vt)
-    out = out.transpose(0, 2, 1, 3).reshape(b, s, h * hd)
-    return out @ p["wo"], new_kv
+    out = out.transpose(0, 2, 1, 3).reshape(b, s, h * hd) @ p["wo"]
+    if cfg.proj_bias:
+        out = out + p["bo"]
+    return out, new_kv
 
 
 # ---------------------------------------------------------------------------
@@ -220,9 +230,13 @@ def attention(x: jnp.ndarray, p: Dict, cfg: LMConfig, *,
 # ---------------------------------------------------------------------------
 
 def mlp(x: jnp.ndarray, p: Dict, cfg: LMConfig) -> jnp.ndarray:
+    def lin(name, h):
+        y = h @ p[f"w{name}"]
+        return y + p[f"b{name}"] if cfg.proj_bias else y
+
     if cfg.mlp_gated:
-        return (jax.nn.silu(x @ p["wg"]) * (x @ p["wu"])) @ p["wd"]
-    return jax.nn.gelu(x @ p["wu"], approximate=True) @ p["wd"]
+        return lin("d", jax.nn.silu(lin("g", x)) * lin("u", x))
+    return lin("d", jax.nn.gelu(lin("u", x), approximate=True))
 
 
 # ---------------------------------------------------------------------------

@@ -63,6 +63,8 @@ def _attn_p(key, cfg: LMConfig, cross: bool = False) -> Dict:
         p["bq"] = jnp.zeros((h * hd,), _dt(cfg))
         p["bk"] = jnp.zeros((kv * hd,), _dt(cfg))
         p["bv"] = jnp.zeros((kv * hd,), _dt(cfg))
+    if cfg.proj_bias:
+        p["bo"] = jnp.zeros((d,), _dt(cfg))
     return p
 
 
@@ -70,11 +72,16 @@ def _mlp_p(key, cfg: LMConfig, d_ff: int) -> Dict:
     d = cfg.d_model
     ks = jax.random.split(key, 3)
     if cfg.mlp_gated:
-        return {"wg": _mat(ks[0], (d, d_ff), cfg),
-                "wu": _mat(ks[1], (d, d_ff), cfg),
-                "wd": _mat(ks[2], (d_ff, d), cfg)}
-    return {"wu": _mat(ks[0], (d, d_ff), cfg),
-            "wd": _mat(ks[1], (d_ff, d), cfg)}
+        p = {"wg": _mat(ks[0], (d, d_ff), cfg),
+             "wu": _mat(ks[1], (d, d_ff), cfg),
+             "wd": _mat(ks[2], (d_ff, d), cfg)}
+    else:
+        p = {"wu": _mat(ks[0], (d, d_ff), cfg),
+             "wd": _mat(ks[1], (d_ff, d), cfg)}
+    if cfg.proj_bias:
+        p.update({f"b{k[1]}": jnp.zeros((v.shape[1],), _dt(cfg))
+                  for k, v in p.items()})
+    return p
 
 
 def _moe_p(key, cfg: LMConfig) -> Dict:
@@ -208,7 +215,8 @@ def init_params(cfg: LMConfig, key) -> Dict:
 def _dense_layer_fwd(x, lp, cfg: LMConfig, positions):
     h = L.apply_norm(x, lp["ln1"], cfg)
     h = shard_hint(h, BATCH, None, None)
-    attn_out, _ = L.attention(h, lp["attn"], cfg, positions=positions)
+    attn_out, _ = L.attention(h, lp["attn"], cfg, positions=positions,
+                              window=cfg.local_window)
     x = x + attn_out
     h = L.apply_norm(x, lp["ln2"], cfg)
     aux = jnp.zeros((), jnp.float32)
@@ -241,7 +249,8 @@ def _run_stacked(params, cfg: LMConfig, x, positions, collect_kv=False):
     def body_kv(carry, lp):
         # dense-family prefill: also emit this layer's rope'd K/V
         h = L.apply_norm(carry, lp["ln1"], cfg)
-        attn_out, kv = L.attention(h, lp["attn"], cfg, positions=positions)
+        attn_out, kv = L.attention(h, lp["attn"], cfg, positions=positions,
+                                   window=cfg.local_window)
         x2 = carry + attn_out
         h2 = L.apply_norm(x2, lp["ln2"], cfg)
         if cfg.family == "moe":
@@ -511,14 +520,15 @@ def prefill(params, cfg: LMConfig, tokens, *, max_len: int,
             h = L.apply_norm(x, lp["ln2"], cfg)
             x = x + L.mlp(h, lp["mlp"], cfg)
 
-    if cfg.family in ("dense", "moe", "vlm"):
-        pass   # x already final from scan
-    logits, _ = (_logits(params, cfg, x), None)
-    return cache, logits[:, -1]
+    # the head at the last position only: the norm is per position
+    return cache, _logits(params, cfg, x[:, -1:])[:, 0]
 
 
-def _token_attn_decode(h, lp_attn, cfg, kc, vc, pos, cache_len, window=0):
-    """One-token attention against (and updating) a cache."""
+def _token_attn_decode(h, lp_attn, cfg, kc, vc, pos, cache_len, ring=0,
+                       window=0):
+    """One-token attention against (and updating) a cache: a ring of
+    ``ring`` slots (position ``p`` at slot ``p % ring``), or a contiguous
+    cache whose keys outside a sliding ``window`` are masked."""
     b = h.shape[0]
     kv, hd, hq = cfg.n_kv, cfg.head_dim, cfg.n_heads
     q = (h @ lp_attn["wq"])
@@ -532,12 +542,15 @@ def _token_attn_decode(h, lp_attn, cfg, kc, vc, pos, cache_len, window=0):
     posv = jnp.full((b, 1), pos)
     q = L.rope(q, posv, cfg.rope_theta)
     k = L.rope(k, posv, cfg.rope_theta)
-    write_at = pos % window if window else pos
+    write_at = pos % ring if ring else pos
     kc, vc = _write_kv(kc, vc, (k.transpose(0, 2, 1, 3),
                                 v.transpose(0, 2, 1, 3)), write_at)
-    out = L.decode_attention(q.transpose(0, 2, 1, 3), kc, vc, cache_len)
-    out = out.transpose(0, 2, 1, 3).reshape(b, 1, hq * hd)
-    return out @ lp_attn["wo"], kc, vc
+    out = L.decode_attention(q.transpose(0, 2, 1, 3), kc, vc, cache_len,
+                             window=window)
+    out = out.transpose(0, 2, 1, 3).reshape(b, 1, hq * hd) @ lp_attn["wo"]
+    if cfg.proj_bias:
+        out = out + lp_attn["bo"]
+    return out, kc, vc
 
 
 def decode_step(params, cfg: LMConfig, token, cache, pos):
@@ -553,7 +566,8 @@ def decode_step(params, cfg: LMConfig, token, cache, pos):
             lp, kc, vc = lp_kc_vc
             h = L.apply_norm(h_in, lp["ln1"], cfg)
             out, kc, vc = _token_attn_decode(h, lp["attn"], cfg, kc, vc,
-                                             pos, cache_len)
+                                             pos, cache_len,
+                                             window=cfg.local_window)
             x2 = h_in + out
             h = L.apply_norm(x2, lp["ln2"], cfg)
             if cfg.family == "moe":
@@ -593,7 +607,7 @@ def decode_step(params, cfg: LMConfig, token, cache, pos):
                 clen = jnp.minimum(cache_len, w)
                 out, kc, vc = _token_attn_decode(
                     h, lp["attn"], cfg, cl["k"], cl["v"], pos, clen,
-                    window=w)
+                    ring=w)
                 new_layers.append({"k": kc, "v": vc})
             else:
                 out, (lru, conv) = rglru.recurrent_block(

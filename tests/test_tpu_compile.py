@@ -151,3 +151,31 @@ def test_resnet50_forward_compiles(shape_of):
     assert compiled.out_info.shape == (1, 1000)
     # weights (~100 MB fp32) plus activations fit one 16 GiB chip
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 2**30
+
+
+def test_starcoder2_decode_compiles(shape_of):
+    """The served decode step of StarCoder2-3B at its published widths,
+    as ``LMSession`` builds it: the bfloat16 weights (6.06 GB) and a
+    4160-position cache fit one chip, and the ops carry the step's
+    ``lm.decode`` scope."""
+    from repro.configs import ARCHS
+    from repro.engine import LMSession
+    from repro.models.lm import init_cache, init_params
+
+    cfg = ARCHS["starcoder2-3b"]
+
+    def place(tree):
+        return jax.tree.map(lambda a: shape_of(a.shape, a.dtype), tree)
+
+    params = place(jax.eval_shape(lambda k: init_params(cfg, k),
+                                  jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(lambda: init_cache(cfg, 1, 4160)))
+    sess = LMSession(cfg, params, max_len=4160)
+    compiled = sess._decode().lower(
+        params, shape_of((1, 1), jnp.int32), cache,
+        shape_of((), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    assert 6.0e9 < mem.argument_size_in_bytes < 6.4e9
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes) < 16e9
+    assert "lm.decode" in compiled.as_text()
