@@ -29,8 +29,9 @@ from repro.engine.fleet import (DuplicateModelError, FleetServer,
 from repro.engine.lm_session import LMSession, compile_lm
 from repro.engine.serving import (AllWorkersUnhealthyError, AsyncServer,
                                   BatchPolicy, DeadlineExceededError,
-                                  DynamicBatchPolicy, LoadShedError,
-                                  QueueFullError, RequestTooLargeError,
+                                  DynamicBatchPolicy, HostRows,
+                                  LoadShedError, QueueFullError,
+                                  RequestTooLargeError,
                                   RetriesExhaustedError,
                                   ServerClosedError, ServingError,
                                   ServingStats, StreamRequest, TokenStream,
@@ -56,6 +57,7 @@ __all__ = ["AllWorkersUnhealthyError", "ArtifactCorruptError",
            "DEFAULT_PRIORITY", "DeadlineExceededError", "DelayBatch",
            "DuplicateModelError", "DynamicBatchPolicy",
            "FailBatch", "FaultInjector", "FleetServer", "HeartbeatMonitor",
+           "HostRows",
            "InferenceSession", "InjectedFault", "InjectedPredictError",
            "LMSession",
            "InjectedWorkerCrash", "KillWorker", "LoadShedError",

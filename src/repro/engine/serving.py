@@ -13,11 +13,12 @@ blocking for M=1 vs M=8, so the same image gets different low bits when
 co-batched), but they *are* invariant to row position and neighbor content
 within one fixed-shape executable.  Serving therefore executes every
 request — packed or alone — through the same bucket-shaped programs:
-``padded_predict`` pads a request up to the nearest specialized batch size
-and slices the real rows back out.  Packed results are bit-identical to
-one-request-at-a-time serving of the same artifact, no matter how the
-traffic interleaved; the throughput win of the driver is that one bucket
-execution serves many requests instead of one.
+``padded_predict`` pads a request up to the nearest specialized batch size,
+copies the output to the host and slices the real rows back out there.
+Packed results are bit-identical to one-request-at-a-time serving of the
+same artifact, no matter how the traffic interleaved; the throughput win
+of the driver is that one bucket execution serves many requests instead
+of one.
 
 Batching policy (``DynamicBatchPolicy``):
 
@@ -108,10 +109,10 @@ session runs; the batch span's args are computed only while one does).
 request's submit to the batch's formation) covers one batch from
 formation to its stats update, with the children ``serving.gather``
 (concatenate and pad), ``serving.dispatch`` (choose the program and call
-it), ``serving.device_wait`` (``block_until_ready``) and
-``serving.scatter`` (slice each request's rows, resolve futures — the
-clients' callbacks run here — and count the batch); the slice that drops
-the padded rows lies between the last two.  A streamed generation gets
+it), ``serving.device_wait`` (block on and copy the batch's output to the
+host: one ``jax.device_get``) and ``serving.scatter`` (host slices of each
+request's rows, resolve futures — the clients' callbacks run here — and
+count the batch; no device dispatch).  A streamed generation gets
 ``serving.batch`` with the LM session's ``lm.*`` spans inside it.  The
 server also holds the collector-pause hook of ``engine/telemetry.py``
 (``runtime.gc`` spans, ``health()["gc"]``).
@@ -134,6 +135,7 @@ from typing import Callable, Deque, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.engine.faults import FaultInjector, InjectedWorkerCrash
@@ -212,6 +214,39 @@ def pad_rows(x: jnp.ndarray, bucket: int) -> jnp.ndarray:
     return jnp.concatenate([x, pad])
 
 
+class HostRows(np.ndarray):
+    """A served answer: rows of a batch's output, copied to the host.
+
+    A ``numpy.ndarray`` that also answers ``devices()`` as the
+    ``jax.Array`` it was copied from did, so a caller can still tell
+    which device (or devices, for a sharded program) computed it.  Views
+    and slices keep the devices."""
+
+    _devices: frozenset = frozenset()
+
+    def __array_finalize__(self, obj) -> None:
+        self._devices = getattr(obj, "_devices", frozenset())
+
+    def devices(self) -> set:
+        return set(self._devices)
+
+
+def _fetch(y):
+    """Copies a batch's output (an array or a tuple of them) to the host
+    in one ``jax.device_get``.  Returns the host arrays, as
+    :class:`HostRows` of the devices that computed them, and their
+    bytes."""
+    leaves = y if isinstance(y, tuple) else (y,)
+    devs = frozenset().union(*(a.devices() for a in leaves))
+    host = []
+    for a in jax.device_get(leaves):
+        h = np.asarray(a).view(HostRows)
+        h._devices = devs
+        host.append(h)
+    nbytes = sum(h.nbytes for h in host)
+    return (tuple(host) if isinstance(y, tuple) else host[0]), nbytes
+
+
 def _slice_rows(y, a: int, b: int):
     if isinstance(y, tuple):
         return tuple(t[a:b] for t in y)
@@ -235,7 +270,8 @@ def _batch_span(batch, worker: int, seq: int, rows: int, bucket: int,
 def padded_predict(session, x: jnp.ndarray, bucket: Optional[int] = None):
     """One request through the serving execution path: pad to the nearest
     specialized bucket (or an explicit ``bucket``), execute that
-    fixed-shape program, slice the real rows back.  This is the
+    fixed-shape program, copy its output to the host and slice the real
+    rows back (:class:`HostRows`, or a tuple of them).  This is the
     *sequential baseline* the driver's packed results are bit-identical
     to (results depend only on the bucket programs, never on which other
     requests shared the batch)."""
@@ -253,7 +289,7 @@ def padded_predict(session, x: jnp.ndarray, bucket: Optional[int] = None):
                 "artifact with a larger bucket or with its source packed")
         bucket = n                       # specialize on demand (locked)
     y = session.specialize(bucket).predict(pad_rows(x, bucket))
-    return _slice_rows(y, 0, n)
+    return _slice_rows(_fetch(y)[0], 0, n)
 
 
 # ---------------------------------------------------------------------------
@@ -518,6 +554,7 @@ class ServingStats:
     n_batches: int = 0
     rows_executed: int = 0         # real request rows
     rows_padded: int = 0           # zero rows added to reach the bucket
+    fetched_bytes: int = 0         # batch outputs copied to the host
     n_retried: int = 0             # request re-executions granted
     n_retries_exhausted: int = 0   # requests failed past their budget
     n_shed: int = 0                # queued requests evicted by overload
@@ -576,6 +613,7 @@ class ServingStats:
             "n_batches": self.n_batches,
             "rows_executed": self.rows_executed,
             "rows_padded": self.rows_padded,
+            "fetched_bytes": self.fetched_bytes,
             "n_retried": self.n_retried,
             "n_retries_exhausted": self.n_retries_exhausted,
             "n_shed": self.n_shed,
@@ -608,7 +646,8 @@ class AsyncServer:
 
     ``submit`` is thread-safe and non-blocking: it enqueues and returns a
     ``concurrent.futures.Future`` that resolves to exactly what
-    ``padded_predict(session, x)`` would return — or a *typed*
+    ``padded_predict(session, x)`` would return (host rows,
+    :class:`HostRows`, or a tuple of them) — or a *typed*
     ``ServingError``; under supervision no request is ever silently lost.
     ``workers`` worker threads pack (FIFO, under one lock) and execute
     batches; with more than one, each worker executes through its own
@@ -1128,6 +1167,7 @@ class AsyncServer:
         rows = sum(r.rows for r in batch)
         stream = isinstance(batch[0], StreamRequest)
         bucket = rows if stream else self._bucket(rows)  # no LM padding
+        fetched = 0
         with _batch_span(batch, worker, seq, rows, bucket, formed):
             try:
                 if self.faults is not None:
@@ -1150,14 +1190,14 @@ class AsyncServer:
                         y = self._model_for(bucket, worker,
                                             xs).predict(xs)
                     with TraceAnnotation("serving.device_wait"):
-                        y = jax.block_until_ready(y)
-                    y = _slice_rows(y, 0, rows)
+                        y, fetched = _fetch(y)
             except BaseException as e:  # noqa: BLE001 — retry or fail typed
                 self._fail_or_requeue(batch, e, worker=worker)
                 if isinstance(e, InjectedWorkerCrash):
                     raise WorkerCrashError(str(e)) from e
                 return
-            # each request's rows (its callbacks run here), then the count
+            # each request's rows, host views (its callbacks run here),
+            # then the count
             with TraceAnnotation("serving.scatter"):
                 done = self._clock()
                 off = 0
@@ -1174,6 +1214,7 @@ class AsyncServer:
                     self._stats.n_batches += 1
                     self._stats.rows_executed += rows
                     self._stats.rows_padded += bucket - rows
+                    self._stats.fetched_bytes += fetched
                     self._stats.batch_hist.add(rows)
                     self._stats.n_completed += n_ok
                     for lat, prio in lats:
@@ -1447,6 +1488,7 @@ class AsyncServer:
                     "queue_depth_peak": self._stats.queue_depth_peak,
                     "arrival_hist": self._stats.arrival_hist.to_json(),
                     "rows_padded": self._stats.rows_padded,
+                    "fetched_bytes": self._stats.fetched_bytes,
                     "mean_batch_rows": self._stats.mean_batch_rows,
                     "latency_ms": {
                         "p50": round(self._stats.percentile_ms(50), 3),

@@ -307,6 +307,7 @@ _REPLICAS_SCRIPT = textwrap.dedent("""
     from repro.core.graph import Graph
     from repro.engine import AsyncServer, DynamicBatchPolicy
     from repro.engine import compile as compile_session
+    from repro.engine.serving import pad_rows
 
     devs = jax.devices()
     assert len(devs) == 4, devs
@@ -335,6 +336,11 @@ _REPLICAS_SCRIPT = textwrap.dedent("""
         f = srv.submit(x)
         assert srv.step()
         ys.append(f.result(timeout=0))
+        # worker 0 ran the batch through the replica on devs[w]
+        rep = srv._model_for(4, 0, pad_rows(x, 4))
+        assert rep.device == devs[w], (w, rep.device)
+        assert {d for p in jax.tree_util.tree_leaves(rep.params)
+                for d in p.devices()} == {devs[w]}, w
         srv.close()
         assert m._forward._cache_size() == 4, (w, m._forward._cache_size())
     assert [next(iter(y.devices())) for y in ys] == devs

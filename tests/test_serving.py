@@ -16,6 +16,7 @@ from repro.engine import (AsyncServer, DeadlineExceededError,
                           ServerClosedError, compile_model, nearest_bucket,
                           padded_predict)
 from repro.engine import compile as compile_session
+from repro.engine.serving import pad_rows
 
 
 def _mini_net():
@@ -118,6 +119,111 @@ def test_padded_batch_slices_back_per_request(session, rng):
     assert yb.tobytes() == packed[3:4].tobytes()
     assert srv.stats.rows_padded == 0       # 3+1 filled the bucket exactly
     srv.close()
+
+
+# ---------------------------------------------------------------------------
+# A batch's output reaches the host in one copy; requests get host views
+# ---------------------------------------------------------------------------
+
+def _two_output_net():
+    g, shapes = _mini_net()
+    g.mark_output("fl")                     # (N, 32) beside fc's (N, 10)
+    return g, shapes
+
+
+@pytest.fixture(scope="module")
+def two_output_session():
+    g, shapes = _two_output_net()
+    sess = compile_session(g, shapes)
+    sess.specialize(4)
+    return sess
+
+
+def _row_bytes(y):
+    leaves = y if isinstance(y, tuple) else (y,)
+    return sum(a[:1].nbytes for a in leaves)
+
+
+@pytest.mark.parametrize("two_outputs", [False, True],
+                         ids=["one_output", "tuple_of_outputs"])
+def test_packed_batch_resolves_host_rows_identical_to_padded_predict(
+        session, two_output_session, rng, two_outputs):
+    """Three requests of different sizes packed into bucket 4: each future
+    resolves to host arrays holding exactly its rows, bit-identical to
+    (and of the same type as) ``padded_predict`` at the same bucket, and
+    the batch's output is counted once in ``fetched_bytes``."""
+    sess = two_output_session if two_outputs else session
+    xs = [_x(rng, 1), _x(rng, 2), _x(rng, 1)]
+    srv, _ = _manual_server(sess)
+    futs = [srv.submit(x) for x in xs]
+    assert srv.step()                       # 1+2+1 rows == max_batch
+    for x, f in zip(xs, futs):
+        got, ref = f.result(0), padded_predict(sess, x, bucket=4)
+        assert type(got) is type(ref)
+        assert isinstance(got, tuple) == two_outputs
+        got_l = got if two_outputs else (got,)
+        ref_l = ref if two_outputs else (ref,)
+        assert len(got_l) == len(ref_l) == (2 if two_outputs else 1)
+        for g, r in zip(got_l, ref_l):
+            assert isinstance(g, np.ndarray)
+            assert g.shape[0] == x.shape[0]
+            assert g.dtype == r.dtype and g.shape == r.shape
+            assert g.tobytes() == r.tobytes()
+    st = srv.stats
+    assert st.n_batches == 1 and st.rows_padded == 0
+    assert st.fetched_bytes == st.n_batches * 4 * _row_bytes(ref)
+    assert st.to_json()["fetched_bytes"] == st.fetched_bytes
+    assert srv.health()["telemetry"]["fetched_bytes"] == st.fetched_bytes
+    srv.close()
+
+
+def test_fetched_bytes_counts_every_batch_at_its_bucket(session, rng):
+    """Each batch copies its whole bucket, padded rows included: 4 rows
+    at bucket 4, 3 rows padded to 4, then 1 row at bucket 1."""
+    srv, clock = _manual_server(session)
+    futs = []
+    for rows in (4, 3, 1):
+        futs.append(srv.submit(_x(rng, rows)))
+        clock.advance_ms(20)                # past max_wait: flush it
+        assert srv.step()
+    row = futs[-1].result(0).nbytes
+    st = srv.stats
+    assert (st.n_batches, st.rows_padded) == (3, 1)
+    assert st.fetched_bytes == (4 + 4 + 1) * row
+    srv.close()
+
+
+def test_scatter_makes_no_device_dispatch(session, rng, monkeypatch):
+    """Once the batch's output is on the host, requests are resolved from
+    host views: no device array is sliced during a step, so no
+    ``dynamic_slice`` program is dispatched a request."""
+    xs = [_x(rng, 1), _x(rng, 2), _x(rng, 1)]
+    refs = [padded_predict(session, x, bucket=4) for x in xs]
+    srv, _ = _manual_server(session)
+    futs = [srv.submit(x) for x in xs]
+
+    def no_device_slice(self, idx):
+        raise AssertionError("a device array was sliced while serving")
+
+    monkeypatch.setattr(type(jnp.zeros(1)), "__getitem__", no_device_slice)
+    assert srv.step()
+    monkeypatch.undo()
+    for f, r in zip(futs, refs):
+        assert f.exception(0) is None
+        assert f.result(0).tobytes() == r.tobytes()
+    assert srv.stats.n_completed == 3 and srv.stats.n_failed == 0
+    srv.close()
+
+
+def test_host_rows_name_the_device_that_computed_them(session, rng):
+    """Served rows are host arrays that still answer ``devices()``, as
+    the device array they were copied from did; views keep it."""
+    x = _x(rng, 3)
+    y = padded_predict(session, x, bucket=4)
+    dev = session.specialize(4).predict(pad_rows(x, 4)).devices()
+    assert y.devices() == dev
+    assert y[1:2].devices() == dev
+    assert type(np.asarray(y)) is np.ndarray
 
 
 # ---------------------------------------------------------------------------
